@@ -43,6 +43,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "src"))
 
 from repro import obs  # noqa: E402
+from repro.launch.cache import use_compile_cache  # noqa: E402
 from repro.launch.serve_qr import QRServer, _as_tuple, make_workload  # noqa: E402
 from repro.serve import (  # noqa: E402
     AdmissionPolicy,
@@ -217,6 +218,7 @@ def main(argv=None) -> None:
                     help="collect repro.obs metrics for the async run and "
                          "write PREFIX.jsonl + PREFIX.prom snapshots")
     args = ap.parse_args(argv)
+    use_compile_cache()
     if args.check:
         args.requests = min(args.requests, 48)
         args.rate = min(args.rate, 600.0)

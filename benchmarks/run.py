@@ -42,6 +42,8 @@ sys.path.insert(0, os.path.join(REPO, "src"))
 import jax
 import jax.numpy as jnp
 
+from repro.launch.cache import use_compile_cache
+
 
 def _time(fn, *args, reps=5, warmup=2):
     for _ in range(warmup):
@@ -175,45 +177,61 @@ def bench_kernels():
     return rows
 
 
-def bench_scaling():
-    """fig. 16 analogue: distributed GGR QR across mesh sizes (subprocess per
-    device count; 1 physical core, so the speedup evidence is the per-device
-    compute share + collective bytes from the compiled SPMD program)."""
+def _device_counts(row_fn):
+    """One ``row_fn(ndev)`` row per device count in (1, 2, 4).
+
+    On a CPU host each count runs in a child process with that many forced
+    host devices (the parent sees one CPU device).  On an accelerator the
+    parent already holds the chips, and a child could not reach them, so
+    every count runs here on the first ``ndev`` of ``jax.devices()``; counts
+    above the host's are skipped.
+    """
     rows = []
     for ndev in (1, 2, 4):
-        code = f"""
-import time, numpy as np, jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.core.distributed import distributed_ggr_qr_1d
-from repro.launch.dryrun import collective_bytes
-mesh = jax.make_mesh(({ndev},), ("x",))
-A = jnp.asarray(np.random.default_rng(0).standard_normal((256, 256)), jnp.float32)
-Aj = jax.device_put(A, NamedSharding(mesh, P(None, "x")))
-fn = jax.jit(lambda X: distributed_ggr_qr_1d(X, mesh, "x", panel=16))
-lowered = fn.lower(Aj); comp = lowered.compile()
-cb = collective_bytes(comp.as_text())["total"]
-jax.block_until_ready(fn(Aj))
-t0 = time.perf_counter()
-for _ in range(3): jax.block_until_ready(fn(Aj))
-t = (time.perf_counter() - t0) / 3 * 1e6
-c = comp.cost_analysis(); c = c[0] if isinstance(c, list) else c
-print(f"RES,{{t:.0f}},{{c.get('flops',0):.3e}},{{cb}}")
-"""
+        if jax.default_backend() != "cpu":
+            if ndev <= jax.device_count():
+                rows.append(row_fn(ndev))
+            continue
         env = dict(os.environ)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
-        env["PYTHONPATH"] = os.path.join(REPO, "src")
+        env["PYTHONPATH"] = os.pathsep.join(
+            [os.path.join(REPO, "src"), os.path.dirname(__file__)])
+        code = f"import run; print('RES,' + run.{row_fn.__name__}({ndev}))"
         out = subprocess.run([sys.executable, "-c", code], env=env,
                              capture_output=True, text=True, timeout=900)
         line = [l for l in out.stdout.splitlines() if l.startswith("RES,")]
-        if not line:
-            rows.append(f"scaling_dev{ndev},0,error={out.stderr[-160:]!r}")
-            continue
-        _, t, flops, cb = line[0].split(",")
-        rows.append(
-            f"scaling_dev{ndev},{float(t):.0f},"
-            f"per_device_flops={flops};collective_bytes={cb}"
-        )
+        rows.append(line[0][4:] if line else
+                    f"{row_fn.__name__}_dev{ndev},0,"
+                    f"error={out.stderr[-160:]!r}")
     return rows
+
+
+def _scaling_row(ndev):
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+
+    from repro.core.distributed import distributed_ggr_qr_1d
+    from repro.launch.dryrun import collective_bytes
+
+    mesh = jax.make_mesh((ndev,), ("x",), axis_types=(AxisType.Auto,))
+    A = jnp.asarray(np.random.default_rng(0).standard_normal((256, 256)),
+                    jnp.float32)
+    Aj = jax.device_put(A, NamedSharding(mesh, P(None, "x")))
+    fn = jax.jit(lambda X: distributed_ggr_qr_1d(X, mesh, "x", panel=16))
+    comp = fn.lower(Aj).compile()
+    cb = collective_bytes(comp.as_text())["total"]
+    t, _ = _time(fn, Aj, reps=3, warmup=1)
+    c = comp.cost_analysis()
+    c = c[0] if isinstance(c, list) else c
+    return (f"scaling_dev{ndev},{t:.0f},"
+            f"per_device_flops={c.get('flops', 0):.3e};collective_bytes={cb}")
+
+
+def bench_scaling():
+    """fig. 16 analogue: distributed GGR QR across mesh sizes (on a CPU host
+    the devices are forced host devices on shared cores, so the speedup
+    evidence is the per-device compute share + collective bytes from the
+    compiled SPMD program)."""
+    return _device_counts(_scaling_row)
 
 
 def bench_update():
@@ -258,39 +276,37 @@ def bench_update():
     return rows
 
 
+_SERVE_REQS = 67
+
+
+def _serve_row(ndev):
+    import contextlib
+    import io
+
+    from repro.launch import serve_qr
+
+    buf = io.StringIO()
+    with contextlib.redirect_stdout(buf):
+        serve_qr.main(["--requests", str(_SERVE_REQS), "--n", "16",
+                       "--rows", "8", "--mesh", str(ndev)])
+    rps = float([l for l in buf.getvalue().splitlines()
+                 if l.startswith("serve_qr_")][0].split(",")[1])
+    return (f"serve_dev{ndev},{1e6 / rps:.0f},req_per_s={rps};"
+            f"requests={_SERVE_REQS};"
+            f"per_shard_batch<={-(-_SERVE_REQS // ndev)}")
+
+
 def bench_serve():
     """Sharded serving: QRServer flush throughput vs device count.
 
-    Subprocess per device count (fake host devices, like bench_scaling; on 1
-    physical core the scaling evidence is the per-shard batch share — each
-    device's kernel sweeps ceil(B/ndev) problems — measured wall-clock is
-    still recorded).  67 requests on purpose: the append group lands at a
+    The per-shard batch share is the scaling evidence on a CPU host (each
+    device's kernel sweeps ceil(B/ndev) problems); measured wall-clock is
+    still recorded.  67 requests on purpose: the append group lands at a
     non-block_b-multiple size, so this row regresses the pad-to-multiple
     path (pre-fix the kernel degraded such batches toward one-problem grid
     steps).
     """
-    rows = []
-    reqs, n, p = 67, 16, 8
-    for ndev in (1, 2, 4):
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
-        env["PYTHONPATH"] = os.path.join(REPO, "src")
-        out = subprocess.run(
-            [sys.executable, "-m", "repro.launch.serve_qr",
-             "--requests", str(reqs), "--n", str(n), "--rows", str(p),
-             "--mesh", str(ndev)],
-            env=env, capture_output=True, text=True, timeout=900)
-        data = [l for l in out.stdout.splitlines() if l.startswith("serve_qr_")]
-        if not data:
-            rows.append(f"serve_dev{ndev},0,error={out.stderr[-160:]!r}")
-            continue
-        rps = float(data[0].split(",")[1])
-        shard_b = -(-reqs // ndev)
-        rows.append(
-            f"serve_dev{ndev},{1e6 / rps:.0f},"
-            f"req_per_s={rps};requests={reqs};per_shard_batch<={shard_b}"
-        )
-    return rows
+    return _device_counts(_serve_row)
 
 
 def bench_kalman():
@@ -641,15 +657,23 @@ def main() -> None:
     if unknown:
         sys.exit(f"unknown bench(es) {unknown}; choose from {sorted(by_name)}")
     benches = [by_name[w] for w in wanted] if wanted else BENCHES
+    use_compile_cache()
     print("name,us_per_call,derived")
+    failed = []
     for bench in benches:
         try:
             for row in bench():
                 print(row, flush=True)
+                if ",error=" in row:
+                    failed.append(row.split(",")[0])
         except SystemExit:
             raise
         except Exception as e:  # pragma: no cover
-            print(f"{bench.__name__},0,ERROR={type(e).__name__}:{e}", flush=True)
+            print(f"{bench.__name__},0,error={type(e).__name__}:{e}",
+                  flush=True)
+            failed.append(bench.__name__)
+    if failed:
+        sys.exit(f"benchmarks failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

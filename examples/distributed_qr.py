@@ -12,7 +12,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.core.distributed import (
     distributed_ggr_qr_1d,
@@ -23,7 +23,7 @@ from repro.launch.dryrun import collective_bytes
 
 
 def main():
-    mesh = jax.make_mesh((8,), ("x",))
+    mesh = jax.make_mesh((8,), ("x",), axis_types=(AxisType.Auto,))
     rng = np.random.default_rng(0)
 
     # 1) block-cyclic panel QR (paper §5, scheme 1)

@@ -209,9 +209,10 @@ def _gemm(lhs, rhs, accum_dtype):
     (``preferred_element_type``) and rounds the result back to tile dtype —
     the GEMM analogue of the kernels' in-body accumulation policy.
     """
+    hi = jax.lax.Precision.HIGHEST  # f32 tiles: not one bf16 pass on TPU
     if accum_dtype is None:
-        return jnp.einsum("pij,pjw->piw", lhs, rhs)
-    return jnp.einsum("pij,pjw->piw", lhs, rhs,
+        return jnp.einsum("pij,pjw->piw", lhs, rhs, precision=hi)
+    return jnp.einsum("pij,pjw->piw", lhs, rhs, precision=hi,
                       preferred_element_type=jnp.dtype(accum_dtype)
                       ).astype(lhs.dtype)
 

@@ -31,38 +31,9 @@ from .ggr import apply_ggr_factors, ggr_column_step_at, ggr_factor_column
 
 __all__ = [
     "distributed_ggr_qr_1d",
-    "shard_map_compat",
     "tsqr",
     "distributed_orthogonalize",
 ]
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` across API generations.
-
-    The stable spelling (jax.shard_map, check_vma=) landed after 0.4.x; older
-    releases ship jax.experimental.shard_map with the check_rep= keyword.
-    Public so other subsystems (the sharded serving path in
-    ``repro.solvers.qr_update`` / ``repro.launch.serve_qr``) map over the
-    same shim instead of re-deriving the version dance.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as esm
-
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
-_shard_map = shard_map_compat  # internal alias, kept for existing call sites
-
-
-def _pvary(x, axes):
-    """jax.lax.pvary where it exists (vma bookkeeping); identity elsewhere."""
-    pvary = getattr(jax.lax, "pvary", None)
-    return x if pvary is None else pvary(x, axes)
 
 
 def _panel_factor_local(panel: jax.Array, global_row0: int = 0):
@@ -175,11 +146,13 @@ def distributed_ggr_qr_1d(
             T = T.at[:, c].set(f.t)
             return X, V, T
 
-        V0 = _pvary(jnp.zeros((m, panel), local.dtype), (axis,))
-        T0 = _pvary(jnp.zeros((m, panel), local.dtype), (axis,))
+        V0 = jax.lax.pcast(jnp.zeros((m, panel), local.dtype), (axis,),
+                           to="varying")
+        T0 = jax.lax.pcast(jnp.zeros((m, panel), local.dtype), (axis,),
+                           to="varying")
         return jax.lax.fori_loop(0, steps, body, (local, V0, T0))
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         kernel, mesh=mesh, in_specs=P(None, axis), out_specs=P(None, axis)
     )
     if layout == "cyclic":
@@ -242,7 +215,7 @@ def tsqr(A: jax.Array, mesh: Mesh, axis: str) -> jax.Array:
 
     # After the reduction tree every device holds the same R; replication is
     # not statically inferable from ppermute, so disable the vma check.
-    fn = _shard_map(
+    fn = jax.shard_map(
         kernel, mesh=mesh, in_specs=P(axis, None), out_specs=P(), check_vma=False
     )
     return fn(A)
@@ -267,7 +240,7 @@ def distributed_orthogonalize(
         return q.T.astype(Al.dtype)
 
     R1 = tsqr(A, mesh, axis)
-    q = _shard_map(
+    q = jax.shard_map(
         lambda Al, R: solve_q(Al, R),
         mesh=mesh,
         in_specs=(P(axis, None), P()),
@@ -275,7 +248,7 @@ def distributed_orthogonalize(
     )(A, R1)
     if refine:
         R2 = tsqr(q, mesh, axis)
-        q = _shard_map(
+        q = jax.shard_map(
             lambda Al, R: solve_q(Al, R),
             mesh=mesh,
             in_specs=(P(axis, None), P()),

@@ -20,65 +20,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .backend import resolve_interpret, resolve_precision
-from .ggr_panel import _EPS, _accum_dt, _revcumsum
+from .ggr_panel import _accum_dt, _column_step, _extract_col, _iota
 
 __all__ = ["apply_factors_pallas"]
 
 
-def _apply_kernel(v_ref, t_ref, c_ref, o_ref, *, pivot0: int, native: bool,
+def _apply_kernel(v_ref, t_ref, c_ref, o_ref, *, pivot0: int,
                   accum_dtype: str | None = None):
     V = v_ref[...]
     T = t_ref[...]
     C = c_ref[...]
     m, b = V.shape
-    cd = C.dtype
     ad = _accum_dt(C, accum_dtype)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (m,), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (b,), 0)
+    rows = _iota((m, 1), 0)
+    cols = _iota((1, b), 1)
 
     def body(c, C):
-        if native:
-            v = jax.lax.dynamic_slice_in_dim(V, c, 1, axis=1)[:, 0]
-            t = jax.lax.dynamic_slice_in_dim(T, c, 1, axis=1)[:, 0]
-        else:
-            onehot = (cols == c).astype(C.dtype)
-            v = V @ onehot  # (m,) one-hot extract
-            t = T @ onehot
-        v = v.astype(ad)
-        t = t.astype(ad)
-        pivot = pivot0 + c
-
-        prod = v[:, None] * C.astype(ad)
-        P = _revcumsum(prod, native=native)  # inclusive suffix sum
-        # exclusive suffix via shift (P - prod would cancel catastrophically)
-        S = jnp.concatenate([P[1:], jnp.zeros_like(P[:1])], axis=0)
-
-        t_next = jnp.concatenate([t[1:], jnp.zeros((1,), t.dtype)])
-        valid = t_next > _EPS
-        safe_t = jnp.where(t > _EPS, t, 1.0)
-        safe_tn = jnp.where(valid, t_next, 1.0)
-        k = v / (safe_t * safe_tn)
-        l = safe_tn / safe_t
-
-        if native:
-            t_piv = jax.lax.dynamic_slice_in_dim(t, pivot, 1, axis=0)[0]
-            P_piv = jax.lax.dynamic_slice_in_dim(P, pivot, 1, axis=0)[0]
-        else:
-            piv_onehot = (rows == pivot).astype(ad)
-            t_piv = (t * piv_onehot).sum()
-            P_piv = piv_onehot @ P
-        pivot_new = (P_piv / jnp.where(t_piv > _EPS, t_piv, 1.0)).astype(cd)
-
-        det2 = k[:-1, None] * S[:-1, :] - l[:-1, None] * C[:-1, :].astype(ad)
-        det2 = jnp.where(valid[:-1, None], det2.astype(cd), C[1:, :])
-        cand_below = jnp.concatenate([C[:1, :], det2], axis=0)
-
-        rr = rows[:, None]
-        do_any = t_piv > _EPS
-        out = jnp.where(
-            rr < pivot, C, jnp.where(rr == pivot, pivot_new[None, :], cand_below)
-        )
-        return jnp.where(do_any, out, C)
+        at_col = cols == c
+        v = _extract_col(V, at_col).astype(ad)  # (m, 1)
+        t = _extract_col(T, at_col).astype(ad)
+        return _column_step(C, v, t, pivot0 + c, rows)[0]
 
     o_ref[...] = jax.lax.fori_loop(0, b, body, C)
 
@@ -92,8 +53,7 @@ def _apply_factors_call(V: jax.Array, T: jax.Array, C: jax.Array,
     w = C.shape[1]
     bw = min(block_w, w)
     assert w % bw == 0, "pad trailing width to the block multiple"
-    kern = functools.partial(_apply_kernel, pivot0=pivot0, native=interpret,
-                             accum_dtype=accum_dtype)
+    kern = functools.partial(_apply_kernel, pivot0=pivot0, accum_dtype=accum_dtype)
     return pl.pallas_call(
         kern,
         grid=(w // bw,),

@@ -7,13 +7,15 @@ TPU co-design notes (the paper's RDP mapping, §4.2 / fig. 12):
 * per column: suffix norms (DOT-chain) + suffix dots + DET2 grid are all
   computed in ONE pass, i.e. the paper's merged UPDATE_ROW1/UPDATE schedule —
   no HBM round-trip between the 2-norm, k/l-vector and trailing updates;
-* column extraction / write-back use one-hot contractions (MXU-friendly,
-  avoids dynamic lane slicing which Mosaic restricts) on the compiled path;
-  the interpret path (``native=True``) uses dynamic slices, which XLA:CPU
-  handles far better than full-width one-hot contractions;
-* the reverse cumulative sums use log2(m) shift-add doubling steps on the
-  compiled path — only static pads, slices and adds, all trivially
-  Mosaic-lowerable — and ``lax.associative_scan`` on the interpret path.
+* every value inside a kernel body is at least 2-D — rows on sublanes,
+  columns on lanes.  Columns are read with a masked lane reduction, pivot
+  rows with a masked sublane reduction (or a dynamic-row ref load in the
+  update kernel), and written back with ``where``: no 1-D vectors, no
+  one-hot matmuls and no dynamic lane slicing, none of which Mosaic lowers;
+* the reverse cumulative sums use log2(m) shift-add doubling steps built
+  from ``jnp.roll`` and a row mask.
+
+Interpret mode (CPU) runs exactly the bodies that Mosaic compiles.
 
 Two kernels:
 
@@ -53,106 +55,135 @@ def _accum_dt(X: jax.Array, accum_dtype: str | None) -> jnp.dtype:
     return X.dtype if accum_dtype is None else jnp.dtype(accum_dtype)
 
 
-def _revcumsum(x: jax.Array, axis: int = 0, native: bool = False) -> jax.Array:
-    """Reverse cumulative sum along ``axis``.
+# Bytes of VMEM one grid step's tiles may plan for: the 16 MiB scoped
+# default of a v5e TensorCore, less headroom for Mosaic's own scratch.
+_VMEM_BUDGET = 12 * 2**20
 
-    ``native=False`` (the Mosaic-lowerable path): log2(m) shift-add doubling
-    steps built from static ``lax.slice_in_dim`` + ``lax.pad`` — no
-    concatenate, so each step is one pad and one add rather than a fresh
-    two-operand buffer assembly.  ``native=True`` (interpret mode):
-    ``lax.associative_scan``, which XLA:CPU executes several times faster
-    than either the doubling ladder or ``lax.cumsum``.
+
+def _fit_block(bb: int, io_rows: int, act_rows: int, width: int) -> int:
+    """Halve ``bb`` until a grid step's tiles fit ``_VMEM_BUDGET``.
+
+    Per problem: the (io_rows, width) in/out tiles, double-buffered, plus
+    about a dozen (act_rows, width) temporaries of the column step, each
+    padded to whole (8, 128) f32 vector tiles.  Grid tiling never changes
+    a result — every problem is swept on its own.
     """
-    if native:
-        return jax.lax.associative_scan(jnp.add, x, axis=axis, reverse=True)
+    lanes = -(-width // 128) * 128
+
+    def tile(rows):
+        return -(-rows // 8) * 8 * lanes * 4
+
+    per_problem = 4 * tile(io_rows) + 12 * tile(act_rows)
+    while bb > 1 and bb * per_problem > _VMEM_BUDGET:
+        bb //= 2
+    return bb
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis % len(shape))
+
+
+def _revcumsum(x: jax.Array, axis: int = 0) -> jax.Array:
+    """Reverse cumulative sum along ``axis``: log2(m) shift-add doubling
+    steps, each a static ``jnp.roll`` masked to zero past the end."""
     m = x.shape[axis]
-    zero = jnp.asarray(0, x.dtype)
+    idx = _iota(x.shape, axis)
     d = 1
     while d < m:
-        # x[i] += x[i + d]  (zero beyond the end) — pad-after replaces the
-        # old concatenate-with-zeros, avoiding the extra buffer assembly
-        pads = [(0, 0, 0)] * x.ndim
-        pads[axis] = (0, d, 0)
-        x = x + jax.lax.pad(jax.lax.slice_in_dim(x, d, m, axis=axis), zero, pads)
+        # x[i] += x[i + d]  (zero beyond the end)
+        x = x + jnp.where(idx < m - d, jnp.roll(x, -d, axis), 0)
         d *= 2
     return x
 
 
-def _ggr_column_update(X, col_onehot, pivot_row, rows, native=False,
-                       accum_dtype=None):
-    """One fused GGR column step on X (m, n); returns updated X and (v, t).
+def _shift_up(x: jax.Array, axis: int) -> jax.Array:
+    """``x[i + 1]`` along ``axis``, zero past the end."""
+    m = x.shape[axis]
+    return jnp.where(_iota(x.shape, axis) < m - 1, jnp.roll(x, -1, axis), 0)
 
-    The column is scaled by its max-abs before the norm/coefficient math
-    (safe-Givens, ref [26] of the paper); all update formulas are
-    scale-invariant so no rescaling of the trailing matrix is needed.
-    Returned (v, t) are the SCALED factors; sigma restores the diagonal.
 
-    ``accum_dtype`` widens the suffix-norm ``_revcumsum`` ladders and the
-    rotation-coefficient chain (t, k, l, DET2) while the tile X stays at its
-    own (possibly bf16) dtype; ``None`` keeps everything at tile dtype.
+def _extract_col(X: jax.Array, at_col: jax.Array) -> jax.Array:
+    """The column of X (..., m, w) selected by ``at_col``, as (..., m, 1).
+
+    A masked lane reduction: exact (one nonzero term per row) and free of
+    the one-hot matmul and dynamic lane slice that Mosaic refuses.
     """
-    m = X.shape[0]
+    return jnp.sum(jnp.where(at_col, X, 0), axis=-1, keepdims=True)
+
+
+def _scaled_column(col: jax.Array, ad) -> tuple[jax.Array, jax.Array]:
+    """(v / sigma, sigma): the active column scaled by its max-abs along the
+    rows (safe-Givens, ref [26] of the paper); all update formulas are
+    scale-invariant, so the trailing matrix needs no rescaling."""
+    v = col.astype(ad)
+    sigma = jnp.max(jnp.abs(v), axis=-2, keepdims=True)
+    return v / jnp.where(sigma > 0, sigma, 1.0), sigma
+
+
+def _column_step(X, v, t, pivot, rows):
+    """One fused GGR column transform applied to X (..., m, w).
+
+    ``v`` (..., m, 1) is the scaled column (zero above ``pivot``) and ``t``
+    its suffix norms, both at accumulation dtype; ``rows`` is a row iota
+    broadcastable against X.  Suffix dots, the pivot row's eq. 2 DOT and the
+    DET2 grid below it are computed in one pass at v's dtype and stored at
+    X's.  Returns ``(out, do_any, t_piv)``; ``do_any`` is False where the
+    column was already zero, and ``out`` is then X unchanged.
+    """
     cd = X.dtype
-    ad = _accum_dt(X, accum_dtype)
-    col = (X * col_onehot[None, :]).sum(axis=1)  # one-hot extract (MXU/VPU)
-    v = jnp.where(rows >= pivot_row, col, 0.0).astype(ad)
-    sigma = jnp.max(jnp.abs(v))
-    v = v / jnp.where(sigma > 0, sigma, 1.0)
-    t2 = _revcumsum((v * v)[:, None], native=native)[:, 0]
-    t = jnp.sqrt(t2)
-
-    prod = v[:, None] * X.astype(ad)
-    P = _revcumsum(prod, native=native)  # P_i = sum_{r>=i} (inclusive)
+    Xa = X.astype(v.dtype)
+    P = _revcumsum(v * Xa, axis=-2)  # inclusive suffix dots
     # exclusive suffix via shift (P - prod would cancel catastrophically)
-    S = jnp.concatenate([P[1:], jnp.zeros_like(P[:1])], axis=0)
-
-    t_next = jnp.concatenate([t[1:], jnp.zeros((1,), t.dtype)])
+    S = _shift_up(P, -2)
+    t_next = _shift_up(t, -2)
     valid = t_next > _EPS
     safe_t = jnp.where(t > _EPS, t, 1.0)
     safe_tn = jnp.where(valid, t_next, 1.0)
     k = v / (safe_t * safe_tn)
     l = safe_tn / safe_t
 
-    # pivot row extracted via one-hot contraction (no dynamic lane slicing):
-    piv_onehot = (rows == pivot_row).astype(ad)
-    t_piv = (t * piv_onehot).sum()
-    pivot_vals = piv_onehot @ P  # (n,) row-1 DOT of eq. 2
-    pivot_new = (pivot_vals / jnp.where(t_piv > _EPS, t_piv, 1.0)).astype(cd)
-
-    det2 = k[:-1, None] * S[:-1, :] - l[:-1, None] * X[:-1, :].astype(ad)
-    det2 = jnp.where(valid[:-1, None], det2.astype(cd), X[1:, :])
-    cand_below = jnp.concatenate([X[:1, :], det2], axis=0)
-
-    rr = rows[:, None]
+    at_piv = rows == pivot
+    t_piv = jnp.sum(jnp.where(at_piv, t, 0), axis=-2, keepdims=True)
+    P_piv = jnp.sum(jnp.where(at_piv, P, 0), axis=-2, keepdims=True)
     do_any = t_piv > _EPS
-    out = jnp.where(
-        rr < pivot_row, X, jnp.where(rr == pivot_row, pivot_new[None, :], cand_below)
-    )
-    out = jnp.where(do_any, out, X)
-    return out, v.astype(cd), t.astype(cd), do_any, sigma.astype(cd)
+    pivot_new = (P_piv / jnp.where(do_any, t_piv, 1.0)).astype(cd)
+
+    # row i's DET2 result is the new row i + 1; it applies where t[i+1] > eps
+    det2 = jnp.roll((k * S - l * Xa).astype(cd), 1, axis=-2)
+    below = jnp.where(t > _EPS, det2, X)
+    out = jnp.where(rows < pivot, X, jnp.where(at_piv, pivot_new, below))
+    return jnp.where(do_any, out, X), do_any, t_piv
 
 
-def _panel_kernel(a_ref, r_ref, v_ref, t_ref, *, pivot0: int, native: bool,
+def _annihilated_col(col, rows, pivot, tp, do_any):
+    """The exact new pivot column: ``tp`` (= sigma·t) at the pivot, zero
+    below, untouched above — or ``col`` itself when nothing was rotated."""
+    new = jnp.where(rows == pivot, tp.astype(col.dtype),
+                    jnp.where(rows < pivot, col, 0))
+    return jnp.where(do_any, new, col)
+
+
+def _panel_kernel(a_ref, r_ref, v_ref, t_ref, *, pivot0: int,
                   accum_dtype: str | None = None):
     X = a_ref[...]
     m, b = X.shape
-    rows = jax.lax.broadcasted_iota(jnp.int32, (m,), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (b,), 0)
+    ad = _accum_dt(X, accum_dtype)
+    rows = _iota((m, 1), 0)
+    cols = _iota((1, b), 1)
 
     def body(c, carry):
         X, V, T = carry
-        onehot = (cols == c).astype(X.dtype)
-        Xn, v, t, do_any, sigma = _ggr_column_update(
-            X, onehot, pivot0 + c, rows, native=native, accum_dtype=accum_dtype
-        )
-        # write the annihilated column exactly: sigma·t[pivot] at pivot, 0 below
-        tp = sigma * (t * (rows == pivot0 + c)).sum()
-        newcol = jnp.where(rows == pivot0 + c, tp, jnp.where(rows < pivot0 + c, Xn @ onehot, 0.0))
-        newcol = jnp.where(do_any, newcol, Xn @ onehot)
-        Xn = Xn * (1.0 - onehot)[None, :] + newcol[:, None] * onehot[None, :]
-        V = V * (1.0 - onehot)[None, :] + v[:, None] * onehot[None, :]
-        T = T * (1.0 - onehot)[None, :] + t[:, None] * onehot[None, :]
-        return Xn, V, T
+        at_col = cols == c
+        piv = pivot0 + c
+        col = _extract_col(X, at_col)
+        v, sigma = _scaled_column(jnp.where(rows >= piv, col, 0), ad)
+        t = jnp.sqrt(_revcumsum(v * v, axis=0))
+        out, do_any, t_piv = _column_step(X, v, t, piv, rows)
+        newcol = _annihilated_col(col, rows, piv, sigma * t_piv, do_any)
+        X = jnp.where(at_col, newcol, out)
+        V = jnp.where(at_col, v.astype(X.dtype), V)
+        T = jnp.where(at_col, t.astype(X.dtype), T)
+        return X, V, T
 
     V0 = jnp.zeros((m, b), X.dtype)
     T0 = jnp.zeros((m, b), X.dtype)
@@ -167,7 +198,7 @@ def _panel_kernel(a_ref, r_ref, v_ref, t_ref, *, pivot0: int, native: bool,
 def _panel_factor_call(panel: jax.Array, pivot0: int, interpret: bool,
                        accum_dtype: str | None = None):
     m, b = panel.shape
-    kern = functools.partial(_panel_kernel, pivot0=pivot0, native=interpret,
+    kern = functools.partial(_panel_kernel, pivot0=pivot0,
                              accum_dtype=accum_dtype)
     out_shapes = (
         jax.ShapeDtypeStruct((m, b), panel.dtype),
@@ -208,70 +239,22 @@ def panel_factor_pallas(panel: jax.Array, pivot0: int = 0,
 # ---------------------------------------------------------------------------
 # Batched dense GEQRT sweeps (the blocked driver's tile kernel)
 # ---------------------------------------------------------------------------
-def _batched_geqrt_kernel(x_ref, o_ref, *, n_pivots: int, native: bool,
+def _batched_geqrt_kernel(x_ref, o_ref, *, n_pivots: int,
                           accum_dtype: str | None = None):
     X = x_ref[...]  # (bb, t, w) — this grid step's tiles
     bb, t, w = X.shape
-    cd = X.dtype
     ad = _accum_dt(X, accum_dtype)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (t,), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (w,), 0)
+    rows = _iota((1, t, 1), 1)
+    cols = _iota((1, 1, w), 2)
 
     def body(c, X):
-        if native:
-            v = jax.lax.dynamic_slice_in_dim(X, c, 1, axis=2)[..., 0]  # (bb, t)
-        else:
-            oh = (cols == c).astype(X.dtype)
-            v = jnp.einsum("btw,w->bt", X, oh)
-        v = jnp.where(rows[None, :] >= c, v, 0.0).astype(ad)
-        sigma = jnp.max(jnp.abs(v), axis=1, keepdims=True)  # safe-Givens scale
-        vs = v / jnp.where(sigma > 0, sigma, 1.0)
-        ts = jnp.sqrt(_revcumsum(vs * vs, axis=1, native=native))
-
-        prod = vs[:, :, None] * X.astype(ad)
-        P = _revcumsum(prod, axis=1, native=native)  # inclusive suffix dots
-        # exclusive suffix via shift (P - prod cancels catastrophically)
-        S = jnp.concatenate([P[:, 1:], jnp.zeros_like(P[:, :1])], axis=1)
-
-        tn = jnp.concatenate([ts[:, 1:], jnp.zeros_like(ts[:, :1])], axis=1)
-        valid = tn > _EPS
-        st = jnp.where(ts > _EPS, ts, 1.0)
-        stn = jnp.where(valid, tn, 1.0)
-        k = vs / (st * stn)
-        l = stn / st
-
-        if native:
-            t_piv = jax.lax.dynamic_slice_in_dim(ts, c, 1, axis=1)[:, 0]
-            P_piv = jax.lax.dynamic_slice_in_dim(P, c, 1, axis=1)[:, 0]
-        else:
-            piv = (rows == c).astype(ad)
-            t_piv = ts @ piv
-            P_piv = jnp.einsum("r,brw->bw", piv, P)
-        do_any = t_piv > _EPS
-        pivot_new = (P_piv / jnp.where(do_any, t_piv, 1.0)[:, None]).astype(cd)
-
-        det2 = k[:, :-1, None] * S[:, :-1] - l[:, :-1, None] * X[:, :-1].astype(ad)
-        det2 = jnp.where(valid[:, :-1, None], det2.astype(cd), X[:, 1:])
-        cand_below = jnp.concatenate([X[:, :1], det2], axis=1)
-
-        rr = rows[None, :, None]
-        out = jnp.where(rr < c, X, jnp.where(rr == c, pivot_new[:, None, :], cand_below))
-        out = jnp.where(do_any[:, None, None], out, X)
-
-        # annihilated column written exactly: sigma·t at the pivot, 0 below
-        if native:
-            oldcol = jax.lax.dynamic_slice_in_dim(out, c, 1, axis=2)[..., 0]
-        else:
-            oldcol = jnp.einsum("btw,w->bt", out, oh)
-        newcol = jnp.where(rows[None, :] == c,
-                           (sigma[:, 0] * t_piv).astype(cd)[:, None],
-                           jnp.where(rows[None, :] < c, oldcol, 0.0))
-        newcol = jnp.where(do_any[:, None], newcol, oldcol)
-        if native:
-            out = jax.lax.dynamic_update_slice_in_dim(out, newcol[..., None], c, axis=2)
-        else:
-            out = out * (1.0 - oh) + newcol[:, :, None] * oh
-        return out
+        at_col = cols == c
+        col = _extract_col(X, at_col)  # (bb, t, 1)
+        v, sigma = _scaled_column(jnp.where(rows >= c, col, 0), ad)
+        ts = jnp.sqrt(_revcumsum(v * v, axis=1))
+        out, do_any, t_piv = _column_step(X, v, ts, c, rows)
+        newcol = _annihilated_col(col, rows, c, sigma * t_piv, do_any)
+        return jnp.where(at_col, newcol, out)
 
     o_ref[...] = jax.lax.fori_loop(0, n_pivots, body, X)
 
@@ -283,11 +266,11 @@ def _batched_geqrt_call(tiles: jax.Array, n_pivots: int, block_b: int,
     from .ggr_update import pad_batch  # deferred: sibling-module edge
 
     B, t, w = tiles.shape
-    bb = min(block_b, B)
+    bb = _fit_block(min(block_b, B), t, t, w)
     padded = pad_batch(tiles, bb)
     Bpad = padded.shape[0]
     kern = functools.partial(_batched_geqrt_kernel, n_pivots=n_pivots,
-                             native=interpret, accum_dtype=accum_dtype)
+                             accum_dtype=accum_dtype)
     out = pl.pallas_call(
         kern,
         grid=(Bpad // bb,),

@@ -39,7 +39,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .backend import resolve_interpret, resolve_precision
-from .ggr_panel import _EPS, _accum_dt, _revcumsum
+from .ggr_panel import (_accum_dt, _annihilated_col, _column_step,
+                        _extract_col, _fit_block, _iota, _revcumsum,
+                        _scaled_column)
 
 __all__ = ["batched_update_pallas", "pad_batch", "pad_to_tile"]
 
@@ -88,77 +90,39 @@ def pad_to_tile(x: jax.Array, tiles, axes=None) -> jax.Array:
     return jnp.pad(x, widths)
 
 
-def _batched_update_kernel(x_ref, o_ref, *, n_pivots: int, native: bool = False,
+def _batched_update_kernel(x_ref, o_ref, *, n_pivots: int,
                            accum_dtype: str | None = None):
-    X = x_ref[...]  # (bb, n_top + p, w) — this grid step's stacked problems
-    bb, m, w = X.shape
-    cd = X.dtype
-    ad = _accum_dt(X, accum_dtype)
-    n_top = n_pivots
-    Xt, Xu = X[:, :n_top, :], X[:, n_top:, :]  # R|d rows, appended rows
-    rows_t = jax.lax.broadcasted_iota(jnp.int32, (n_top,), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (w,), 0)
+    """Sweep a (bb, m, w) block; rows ``n_pivots - 1 ..`` form the active set.
 
-    def body(c, carry):
-        Xt, Xu = carry
-        if native:
-            r_row = jax.lax.dynamic_slice_in_dim(Xt, c, 1, axis=1)[:, 0]
-        else:
-            piv = (rows_t == c).astype(X.dtype)
-            r_row = jnp.einsum("r,brw->bw", piv, Xt)  # one-hot extract row c
-        A = jnp.concatenate([r_row[:, None, :], Xu], axis=1)  # (bb, p+1, w)
+    The active block A (bb, m - n_pivots + 1, w) is carried through the
+    loop: row 0 is the pivot slot, rows 1.. the appended rows (plus the
+    zero rows the wrapper pads with).  Step c loads R's row c into the slot
+    by a dynamic row read of the output ref, rotates it against the rest of
+    A, and stores the new pivot row back to row c.  The last step's pivot
+    row is row ``n_pivots - 1`` itself, so writing the final A back at that
+    offset completes the output.
+    """
+    bb, m, w = x_ref.shape
+    ad = _accum_dt(x_ref, accum_dtype)
+    o_ref[...] = x_ref[...]
+    rows = _iota((1, m - n_pivots + 1, 1), 1)
+    cols = _iota((1, 1, w), 2)
 
-        if native:
-            v = jax.lax.dynamic_slice_in_dim(A, c, 1, axis=2)[..., 0]
-        else:
-            onehot = (cols == c).astype(X.dtype)
-            v = A @ onehot  # (bb, p+1) — active column: [R_cc; U[:, c]]
-        v = v.astype(ad)
-        sigma = jnp.max(jnp.abs(v), axis=1, keepdims=True)  # safe-Givens scale
-        v = v / jnp.where(sigma > 0, sigma, 1.0)
-        t = jnp.sqrt(_revcumsum(v * v, axis=1, native=native))
+    def body(c, A):
+        A = jnp.where(rows == 0, o_ref[:, pl.ds(c, 1), :], A)
+        at_col = cols == c
+        col = _extract_col(A, at_col)  # active column: [R_cc; U[:, c]]
+        v, sigma = _scaled_column(col, ad)
+        t = jnp.sqrt(_revcumsum(v * v, axis=1))
+        out, do_any, t_piv = _column_step(A, v, t, 0, rows)
+        A = jnp.where(at_col,
+                      _annihilated_col(col, rows, 0, sigma * t_piv, do_any),
+                      out)
+        o_ref[:, pl.ds(c, 1), :] = A[:, :1, :]
+        return A
 
-        prod = v[..., None] * A.astype(ad)
-        P = _revcumsum(prod, axis=1, native=native)  # inclusive suffix dots
-        # exclusive suffix via shift (P - prod cancels catastrophically)
-        S = jnp.concatenate([P[:, 1:], jnp.zeros_like(P[:, :1])], axis=1)
-
-        t_next = jnp.concatenate([t[:, 1:], jnp.zeros_like(t[:, :1])], axis=1)
-        valid = t_next > _EPS
-        safe_t = jnp.where(t > _EPS, t, 1.0)
-        safe_tn = jnp.where(valid, t_next, 1.0)
-        k = v / (safe_t * safe_tn)
-        l = safe_tn / safe_t
-
-        t_piv = t[:, 0]  # pivot is row 0 of the active block
-        do_any = t_piv > _EPS
-        pivot_new = (P[:, 0] / jnp.where(do_any, t_piv, 1.0)[:, None]).astype(cd)
-
-        det2 = k[:, :-1, None] * S[:, :-1] - l[:, :-1, None] * A[:, :-1].astype(ad)
-        det2 = jnp.where(valid[:, :-1, None], det2.astype(cd), A[:, 1:])
-        A_new = jnp.concatenate([pivot_new[:, None, :], det2], axis=1)
-        # annihilated column written exactly: sigma·t at the pivot, 0 below
-        newcol = jnp.concatenate(
-            [(sigma * t_piv[:, None]).astype(cd),
-             jnp.zeros((bb, A.shape[1] - 1), X.dtype)],
-            axis=1,
-        )
-        if native:
-            A_new = jax.lax.dynamic_update_slice_in_dim(
-                A_new, newcol[..., None], c, axis=2
-            )
-            A_new = jnp.where(do_any[:, None, None], A_new, A)
-            Xt = jax.lax.dynamic_update_slice_in_dim(
-                Xt, A_new[:, :1, :], c, axis=1
-            )
-        else:
-            A_new = A_new * (1.0 - onehot) + newcol[..., None] * onehot
-            A_new = jnp.where(do_any[:, None, None], A_new, A)
-            Xt = Xt * (1.0 - piv)[None, :, None] + piv[None, :, None] * A_new[:, :1, :]
-        return Xt, A_new[:, 1:, :]
-
-    Xt, Xu = jax.lax.fori_loop(0, n_pivots, body, (Xt, Xu))
-    o_ref[...] = jnp.concatenate([Xt, Xu], axis=1)
+    A = jax.lax.fori_loop(0, n_pivots, body, x_ref[:, n_pivots - 1:, :])
+    o_ref[:, n_pivots - 1:, :] = A
 
 
 @functools.partial(jax.jit, static_argnames=("n_pivots", "block_b", "interpret",
@@ -171,30 +135,34 @@ def _batched_update_call(stacked: jax.Array, n_pivots: int,
     stacked: (B, n_pivots + p, w) batch of ``[R | d; U | Y]`` matrices, R
     upper triangular (rows n_pivots.. are the appended observation rows).
     Returns the (B, m, w) updated batch; callers slice ``[:, :n, :n]``
-    (updated R) and ``[:, :n, n:]`` (updated rhs).  ``block_b`` problems are
-    processed per grid step (VMEM budget: block_b·m·w elements resident);
-    batches that are not a ``block_b`` multiple are zero-padded up to one
-    (``pad_batch``) and sliced back — never degraded to smaller grid tiles.
+    (updated R) and ``[:, :n, n:]`` (updated rhs).  Each grid step sweeps
+    ``block_b`` problems, or fewer where their tiles would overflow VMEM
+    (``_fit_block``); batches that are not a multiple of that tile are
+    zero-padded (``pad_batch``) and sliced back.
     """
     B, m, w = stacked.shape
     if m < n_pivots:
         raise ValueError(f"stacked rows {m} < n_pivots {n_pivots}")
     if m == n_pivots:  # no appended rows — nothing to annihilate
         return stacked
-    bb = min(block_b, B)
-    padded = pad_batch(stacked, bb)
+    # the active set (pivot slot + p appended rows) is rounded up to whole
+    # sublane tiles with zero rows, which every eps-guarded sweep leaves alone
+    act = -(-(m - n_pivots + 1) // 8) * 8
+    mp = n_pivots - 1 + act
+    bb = _fit_block(min(block_b, B), mp, act, w)
+    padded = pad_to_tile(pad_batch(stacked, bb), (mp,), axes=(1,))
     Bpad = padded.shape[0]
     kern = functools.partial(_batched_update_kernel, n_pivots=n_pivots,
-                             native=interpret, accum_dtype=accum_dtype)
+                             accum_dtype=accum_dtype)
     out = pl.pallas_call(
         kern,
         grid=(Bpad // bb,),
-        out_shape=jax.ShapeDtypeStruct((Bpad, m, w), stacked.dtype),
-        in_specs=[pl.BlockSpec((bb, m, w), lambda i: (i, 0, 0))],
-        out_specs=pl.BlockSpec((bb, m, w), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((Bpad, mp, w), stacked.dtype),
+        in_specs=[pl.BlockSpec((bb, mp, w), lambda i: (i, 0, 0))],
+        out_specs=pl.BlockSpec((bb, mp, w), lambda i: (i, 0, 0)),
         interpret=interpret,
     )(padded)
-    return out[:B]
+    return out[:B, :m]
 
 
 def batched_update_pallas(stacked: jax.Array, n_pivots: int,

@@ -67,6 +67,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.launch.cache import use_compile_cache
 from repro.serve import (ContinuousBatcher, Dispatcher, ResilientDispatcher,
                          Ticket)
 from repro.serve.requests import KINDS as _KINDS
@@ -324,6 +325,7 @@ def main(argv=None):
                     help="collect obs metrics and write PREFIX.jsonl + "
                          "PREFIX.prom snapshots (default: $REPRO_OBS_SNAPSHOT)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     mesh = None
     if args.mesh > 1:

@@ -18,7 +18,7 @@ import dataclasses
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 SERVE_BATCH_AXIS = "batch"
 
@@ -40,7 +40,7 @@ def make_batch_mesh(num_devices: int | None = None,
             f"requested a {n}-device batch mesh but only {avail} devices are "
             f"visible (on CPU set XLA_FLAGS=--xla_force_host_platform_"
             f"device_count={n} before importing jax)")
-    return jax.make_mesh((n,), (axis,))
+    return jax.make_mesh((n,), (axis,), axis_types=(AxisType.Auto,))
 
 
 def batch_shard_spec(ndim: int, axis: str = SERVE_BATCH_AXIS) -> P:

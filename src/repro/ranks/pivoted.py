@@ -161,12 +161,13 @@ def _min_norm_from_state(R, d, perm, tail, rank):
     dm = jnp.where(keep, d, 0.0)
     T, Q2 = ggr_qr2(Rm.T, want_q=True)      # (n, mm) triu, (n, n)
     z = solve_triangular(jnp.triu(T[:mm]), dm, trans=True)
-    y = Q2[:, :mm] @ z                       # min-norm solution, permuted coords
+    hi = jax.lax.Precision.HIGHEST  # the TPU's default is one bf16 pass
+    y = jnp.matmul(Q2[:, :mm], z, precision=hi)  # min-norm x, permuted coords
     x = jnp.zeros((n, d.shape[1]), y.dtype).at[perm].set(y)
     # honest residual: the dropped rows of the *unmasked* state still hold
     # (small) mass — score y against them, plus the reduced-away tail
     f32 = jnp.promote_types(R.dtype, jnp.float32)
-    rrows = (d - R @ y).astype(f32)
+    rrows = (d - jnp.matmul(R, y, precision=hi)).astype(f32)
     resid = jnp.sqrt(jnp.sum(rrows * rrows, axis=0) + tail)
     return x, resid.astype(R.dtype)
 

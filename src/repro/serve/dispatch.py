@@ -128,12 +128,14 @@ def _build_sharded_lstsq(mesh, mesh_axis: str):
     """jit'd shard_map lstsq dispatch for one mesh (cached per server)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.distributed import shard_map_compat
-
-    return jax.jit(shard_map_compat(
+    # check_vma off, as in ``solvers.qr_update``: the map runs each shard's
+    # problems on their own (no collectives), and neither pallas_call nor
+    # the solvers' loop carries are annotated with varying axes
+    return jax.jit(jax.shard_map(
         _batched_lstsq, mesh=mesh,
         in_specs=(P(mesh_axis), P(mesh_axis)),
         out_specs=(P(mesh_axis), P(mesh_axis)),
+        check_vma=False,
     ))
 
 
@@ -158,12 +160,11 @@ def _build_sharded_lstsq_pivoted(mesh, mesh_axis: str):
     server)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.distributed import shard_map_compat
-
-    return jax.jit(shard_map_compat(
+    return jax.jit(jax.shard_map(
         _batched_lstsq_pivoted, mesh=mesh,
         in_specs=(P(mesh_axis), P(mesh_axis)),
         out_specs=(P(mesh_axis), P(mesh_axis), P(mesh_axis)),
+        check_vma=False,
     ))
 
 

@@ -63,6 +63,9 @@ from .qr_update import (
     qr_append_rows,
 )
 
+# the prediction's matmuls at full f32 (the TPU's default is one bf16 pass)
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 __all__ = [
     "KalmanState",
     "KalmanTrajectory",
@@ -158,7 +161,7 @@ def _apply_F_inv(R, F):
     batched == sequential bitwise contract of ``kf_step_batched``.
     """
     U, Theta = ggr_qr2(F.T, want_q=True)
-    return solve_triangular(U, Theta.T @ R.T).T
+    return solve_triangular(U, jnp.matmul(Theta.T, R.T, precision=_HIGHEST)).T
 
 
 def _predict_blocks(R, d, F, Qi, G):
@@ -166,7 +169,7 @@ def _predict_blocks(R, d, F, Qi, G):
     n = R.shape[0]
     w = Qi.shape[0]
     Rd = _apply_F_inv(R, F)
-    RdG = Rd if G is None else Rd @ G
+    RdG = Rd if G is None else jnp.matmul(Rd, G, precision=_HIGHEST)
     top = jnp.concatenate([Qi, jnp.zeros((w, n + 1), R.dtype)], axis=1)
     mid = jnp.concatenate([-RdG, Rd, d[:, None]], axis=1)
     return top, mid

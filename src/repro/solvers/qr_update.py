@@ -47,7 +47,8 @@ def _tri_solve_lower(L: jax.Array, B: jax.Array) -> jax.Array:
     """Forward substitution L x = B for lower-triangular L; B is (n, k).
 
     Row-sequential scan (n steps of an n·k DOT each) — the DOT-chain dual of
-    the suffix-sum sweeps used everywhere else; no LAPACK dependency.
+    the suffix-sum sweeps used everywhere else; no LAPACK dependency.  The
+    DOTs ask for full f32 precision: the TPU's default is one bf16 pass.
     """
     n = L.shape[0]
     f32 = jnp.promote_types(L.dtype, jnp.float32)
@@ -59,7 +60,7 @@ def _tri_solve_lower(L: jax.Array, B: jax.Array) -> jax.Array:
     def body(i, X):
         # x_i = (b_i - L[i, :] @ x) / L_ii ; x_j = 0 for j >= i so the full
         # row dot only picks up already-solved entries.
-        s = La[i] @ X
+        s = jnp.matmul(La[i], X, precision=jax.lax.Precision.HIGHEST)
         xi = (Ba[i] - s) / safe_diag[i]
         return X.at[i].set(xi)
 
@@ -128,11 +129,9 @@ def _sharded_update_fn(mesh, mesh_axis: str, n: int, backend: str,
     ``repro.serve.dispatch`` is the primary cache; this is the backstop)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.distributed import shard_map_compat
-
     # check_vma off: pallas_call has no replication rule; the map is
     # trivially element-wise over shards (no collectives), so safe.
-    return jax.jit(shard_map_compat(
+    return jax.jit(jax.shard_map(
         lambda x: _update_stacked(x, n, backend, interpret, block_b,
                                   precision=precision),
         mesh=mesh,
@@ -160,11 +159,11 @@ def qr_append_rows_batched(R: jax.Array, U: jax.Array,
 
     Sharded mode: pass a ``jax.sharding.Mesh`` and the name of its batch axis
     (default "batch") to split the batch over the mesh with one kernel
-    launch per shard (``shard_map`` via the ``core.distributed`` version
-    shim).  The batch is zero-padded up to ``shards x block_b`` — every shard
-    gets an identical, full-granularity grid — and the padding is sliced off
-    afterwards, so any batch size (including prime sizes and B < shards) is
-    legal and numerically identical to the single-device dispatch.
+    launch per shard (``jax.shard_map``).  The batch is zero-padded up to
+    ``shards x block_b`` — every shard gets an identical, full-granularity
+    grid — and the padding is sliced off afterwards, so any batch size
+    (including prime sizes and B < shards) is legal and numerically
+    identical to the single-device dispatch.
     """
     n = R.shape[2]
     if (d is None) != (Y is None):

@@ -31,9 +31,9 @@ def test_distributed_qr_1d_4dev():
         """
         import numpy as np, jax, jax.numpy as jnp
         jax.config.update("jax_enable_x64", True)
-        from jax.sharding import PartitionSpec as P, NamedSharding
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.core.distributed import distributed_ggr_qr_1d
-        mesh = jax.make_mesh((4,), ("x",))
+        mesh = jax.make_mesh((4,), ("x",), axis_types=(AxisType.Auto,))
         A = np.random.default_rng(0).standard_normal((64, 32))
         Aj = jax.device_put(jnp.array(A), NamedSharding(mesh, P(None, "x")))
         R = np.asarray(distributed_ggr_qr_1d(Aj, mesh, "x", panel=4))
@@ -49,9 +49,9 @@ def test_tsqr_and_orthogonalize_8dev():
         """
         import numpy as np, jax, jax.numpy as jnp
         jax.config.update("jax_enable_x64", True)
-        from jax.sharding import PartitionSpec as P, NamedSharding
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.core.distributed import tsqr, distributed_orthogonalize
-        mesh = jax.make_mesh((8,), ("x",))
+        mesh = jax.make_mesh((8,), ("x",), axis_types=(AxisType.Auto,))
         B = np.random.default_rng(1).standard_normal((128, 16))
         Bj = jax.device_put(jnp.array(B), NamedSharding(mesh, P("x", None)))
         Rt = np.asarray(tsqr(Bj, mesh, "x"))
@@ -70,9 +70,9 @@ def test_tsqr_collectives_present():
     out = _run(
         """
         import numpy as np, jax, jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P, NamedSharding
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.core.distributed import tsqr
-        mesh = jax.make_mesh((4,), ("x",))
+        mesh = jax.make_mesh((4,), ("x",), axis_types=(AxisType.Auto,))
         B = jnp.zeros((64, 8), jnp.float32)
         Bj = jax.device_put(B, NamedSharding(mesh, P("x", None)))
         lowered = jax.jit(lambda X: tsqr(X, mesh, "x")).lower(Bj)

@@ -74,3 +74,51 @@ def test_degenerate_panel_zero_column():
     Rr, Vr, Tr = ref.ref_panel_factor(jnp.array(pan))
     assert np.isfinite(np.asarray(R)).all()
     np.testing.assert_allclose(np.asarray(R), np.asarray(Rr), atol=1e-4)
+
+
+def _append_stack(n_piv, p, w, seed):
+    rng = np.random.default_rng(seed)
+    top = np.triu(rng.standard_normal((n_piv, w)))
+    np.fill_diagonal(top, np.abs(np.diag(top)) + 1.0)
+    return np.concatenate([top, rng.standard_normal((p, w))])
+
+
+@pytest.mark.parametrize("case", ["panel", "apply", "geqrt", "update_append",
+                                  "update_kalman"])
+def test_chip_body_at_smoke_widths_matches_ref(case):
+    """The kernel bodies Mosaic compiles, run in interpret mode at the
+    widths chip_smoke.py serves (n=128 appends and Kalman steps, 512x128
+    blocked solves with 64-wide panels), against the pure-jnp oracles."""
+    from repro.core.ggr import ggr_triangularize
+    from repro.kernels.ggr_update import batched_update_pallas
+
+    rng = np.random.default_rng(11)
+    if case in ("panel", "apply"):
+        pan = jnp.asarray(rng.standard_normal((512, 64)), jnp.float32)
+        if case == "panel":
+            got = ops.panel_qr(pan, interpret=True)
+            want = ref.ref_panel_factor(pan.astype(jnp.float64))
+        else:
+            _, V, T = ref.ref_panel_factor(pan.astype(jnp.float64))
+            C = rng.standard_normal((512, 129))
+            got = [ops.apply_panel(V.astype(jnp.float32), T.astype(jnp.float32),
+                                   jnp.asarray(C, jnp.float32), block_w=129,
+                                   interpret=True)]
+            want = [ref.ref_apply_factors(V, T, jnp.asarray(C))]
+    else:
+        n_piv, p, w = {"geqrt": (64, 0, 128), "update_append": (128, 8, 129),
+                       "update_kalman": (256, 136, 257)}[case]
+        if case == "geqrt":
+            X = rng.standard_normal((2, 64, w))
+            got = [ops.batched_geqrt(jnp.asarray(X, jnp.float32), n_piv,
+                                     interpret=True)]
+        else:
+            X = np.stack([_append_stack(n_piv, p, w, s) for s in (1, 2)])
+            got = [batched_update_pallas(jnp.asarray(X, jnp.float32), n_piv,
+                                         interpret=True)[:, :n_piv]]
+        want = [jnp.stack([ggr_triangularize(jnp.asarray(x), n_piv)
+                           for x in X])[:, :len(got[0][0])]]
+    for g, r in zip(got, want):
+        g, r = np.asarray(g, np.float64), np.asarray(r)
+        assert np.isfinite(g).all()
+        assert np.linalg.norm(g - r) <= 1e-5 * np.linalg.norm(r), case

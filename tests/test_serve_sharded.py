@@ -13,6 +13,7 @@ import os
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -281,3 +282,25 @@ def test_sharded_continuous_batching_matches_single_device_subprocess():
         print("ASYNC_SHARDED_OK")
         """
     )
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_compile_cache_dir_follows_env(monkeypatch, tmp_path, env_dir):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and is left to JAX; without it the
+    cache goes to the one fixed ``.jax_cache/`` of the checkout."""
+    from repro.launch.cache import CHECKOUT_CACHE_DIR, use_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(CHECKOUT_CACHE_DIR)
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    try:
+        assert use_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == (
+            before if env_dir else want)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert CHECKOUT_CACHE_DIR == Path(_REPO) / ".jax_cache"
