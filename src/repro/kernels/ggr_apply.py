@@ -65,6 +65,7 @@ def _apply_factors_call(V: jax.Array, T: jax.Array, C: jax.Array,
         ],
         out_specs=pl.BlockSpec((m, bw), lambda j: (0, j)),
         interpret=interpret,
+        name="ggr_apply_factors",
     )(V, T, C)
 
 

@@ -215,6 +215,7 @@ def _panel_factor_call(panel: jax.Array, pivot0: int, interpret: bool,
             pl.BlockSpec((m, b), lambda: (0, 0)),
         ),
         interpret=interpret,
+        name="ggr_panel_factor",
     )(panel)
 
 
@@ -278,6 +279,7 @@ def _batched_geqrt_call(tiles: jax.Array, n_pivots: int, block_b: int,
         in_specs=[pl.BlockSpec((bb, t, w), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((bb, t, w), lambda i: (i, 0, 0)),
         interpret=interpret,
+        name="ggr_batched_geqrt",
     )(padded)
     return out[:B]
 

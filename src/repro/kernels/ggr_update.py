@@ -161,6 +161,7 @@ def _batched_update_call(stacked: jax.Array, n_pivots: int,
         in_specs=[pl.BlockSpec((bb, mp, w), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((bb, mp, w), lambda i: (i, 0, 0)),
         interpret=interpret,
+        name="ggr_batched_update",
     )(padded)
     return out[:B, :m]
 

@@ -1,10 +1,11 @@
 """repro.obs — metrics, trace spans, and flop accounting for every layer.
 
 Dependency-free observability (stdlib + the jax already in use): a metrics
-registry (counters / gauges / exact-quantile histograms), span helpers over
-``jax.profiler.TraceAnnotation`` + ``jax.named_scope``, wall-clock timers
-that ``block_until_ready`` correctly around asynchronous dispatches, and
-exporters (JSONL snapshots + Prometheus text exposition).
+registry (counters / gauges / exact-quantile histograms), host spans on the
+profiler's clock (``jax.profiler.TraceAnnotation``) and in-graph names
+(``jax.named_scope``), wall-clock timers that ``block_until_ready``
+correctly around asynchronous dispatches, and exporters (JSONL snapshots +
+Prometheus text exposition).
 
 The contract with the hot paths: **nothing is recorded unless a collector
 is installed.**  The default registry is a shared no-op whose ``enabled``
@@ -24,7 +25,7 @@ Quick tour::
     text = obs.prometheus_text(reg)               # exposition text
     q99 = reg.find("serve.queue_wait_seconds", kind="append").quantile(0.99)
 
-    with obs.span("repro/serve/flush/append"):    # host-side span
+    with obs.span("repro/serve/close", kind="append"):  # host span
         out = dispatch(batch)
     with obs.device_timer() as t:                 # honest dispatch timing
         out = kernel(x)
@@ -66,8 +67,8 @@ from .registry import (
     MetricsRegistry,
     NullRegistry,
 )
-from .timing import block_ready, device_timer, time_dispatch
-from .tracing import annotate_fn, named_span, span
+from .timing import block_ready, device_timer
+from .tracing import install_gc_spans, named_span, span
 
 __all__ = [
     "Counter",
@@ -80,7 +81,6 @@ __all__ = [
     "REQUIRED_ASYNC_SERVE_FAMILIES",
     "REQUIRED_RESILIENCE_FAMILIES",
     "REQUIRED_SERVE_FAMILIES",
-    "annotate_fn",
     "block_ready",
     "collecting",
     "condition_estimate",
@@ -94,6 +94,7 @@ __all__ = [
     "ggr_sweep_flops",
     "histogram",
     "install",
+    "install_gc_spans",
     "load_jsonl",
     "lstsq_flops",
     "maybe_sample_orthogonality",
@@ -106,7 +107,6 @@ __all__ = [
     "registry",
     "snapshot",
     "span",
-    "time_dispatch",
     "uninstall",
     "write_jsonl",
     "write_prometheus",

@@ -55,7 +55,6 @@ REQUIRED_SERVE_FAMILIES = (
     "serve.padding_waste",
     "serve.batch_size",
     "serve.requests_served",
-    "serve.achieved_gflops",
 )
 
 # what an instrumented `bench_serve_async --check` run must additionally

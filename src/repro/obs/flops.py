@@ -10,10 +10,12 @@ models (eqs. 3-5 and their rectangular/append generalizations,
 DET2/FMA grids).
 
 ``record_dispatch`` is the single chokepoint every instrumented dispatch
-site funnels through: it observes ``<layer>.dispatch_seconds`` and
-``<layer>.achieved_gflops`` histograms and bumps ``<layer>.dispatches`` —
-one histogram sample per dispatch, which is what "per-dispatch achieved
-GFLOP/s" means in the metric catalog.
+site funnels through: it observes ``<layer>.dispatch_seconds`` and bumps
+``<layer>.dispatches`` and the per-dtype ``<layer>.flops_total``; where the
+seconds are a blocked device time (``rate=True``) it also observes
+``<layer>.achieved_gflops``, one sample per dispatch.  The serving engine
+passes ``rate=False``: its seconds run from the batch close to the pump
+seeing the chunk ready, host time that a flop rate would misread.
 
 ``repro.core.counts`` is imported lazily: ``core.blocked`` imports
 ``repro.obs`` at module scope, and the ``repro.core`` package init imports
@@ -66,14 +68,16 @@ def flops_by_dtype(flops: float, compute_dtype="float32",
 
 
 def record_dispatch(layer: str, flops: float, seconds: float, *,
-                    by_dtype: dict | None = None, **labels) -> None:
-    """Record one timed dispatch: duration + achieved GFLOP/s histograms.
+                    by_dtype: dict | None = None, rate: bool = True,
+                    **labels) -> None:
+    """Record one timed dispatch: duration (and achieved GFLOP/s) histograms.
 
-    ``seconds`` must come from a blocked timer (``obs.device_timer``) or the
-    rate is fiction.  ``by_dtype`` (``{dtype_name: flops}``, e.g. from
-    :func:`flops_by_dtype`) additionally bumps per-dtype
-    ``<layer>.flops_total`` counters so mixed-precision dispatches do not
-    launder bf16 multiplies as f32 throughput.  No-op under the null
+    ``rate=True`` says ``seconds`` came from a blocked timer
+    (``obs.device_timer``), so ``flops / seconds`` is a rate; pass
+    ``rate=False`` for any other clock.  ``by_dtype`` (``{dtype_name:
+    flops}``, e.g. from :func:`flops_by_dtype`) additionally bumps
+    per-dtype ``<layer>.flops_total`` counters so mixed-precision dispatches
+    do not launder bf16 multiplies as f32 throughput.  No-op under the null
     registry.
     """
     reg = _active()
@@ -81,7 +85,7 @@ def record_dispatch(layer: str, flops: float, seconds: float, *,
         return
     reg.counter(f"{layer}.dispatches", **labels).inc()
     reg.histogram(f"{layer}.dispatch_seconds", **labels).observe(seconds)
-    if seconds > 0.0:
+    if rate and seconds > 0.0:
         reg.histogram(f"{layer}.achieved_gflops", **labels).observe(
             flops / seconds / 1e9)
     if by_dtype:

@@ -5,12 +5,11 @@ Two signals, both cheap relative to the solves they watch:
 * **Condition estimate** — min/max ``|diag(R)|`` of a triangular factor,
   plus a real 2-norm condition estimate (``condition_estimate``: a few
   power-iteration rounds for ``smax`` and inverse-iteration rounds through
-  triangular solves for ``smin``, host f64).  The historical
-  ``r_cond_proxy`` gauge was the bare ``max|r_ii| / min|r_ii|`` ratio,
-  which only *lower-bounds* ``cond_2(R)`` — it is kept as an alias carrying
-  the new estimate so stored snapshots stay parseable.  For batched
-  factors the per-member diag ratio screens for the worst member, and only
-  that one pays the O(n^2-per-iter) estimate.  The jit-safe incremental
+  triangular solves for ``smin``, host f64), a far tighter watch than the
+  bare ``max|r_ii| / min|r_ii|`` ratio, which only *lower-bounds*
+  ``cond_2(R)``.  For batched factors the per-member diag ratio screens
+  for the worst member, and only that one pays the O(n^2-per-iter)
+  estimate.  The jit-safe incremental
   variant for streaming states lives in ``repro.ranks.monitor``.
 * **Orthogonality loss** — ``max |Q^T Q - I|`` with ``Q = A R^{-1}``
   reconstructed implicitly (Q is never formed by the GGR paths, so this is
@@ -89,8 +88,7 @@ def factor_health(R, layer: str, **labels) -> None:
     factor (or a (B, n, n) batch of them — the batch-wide excursion is what
     serving wants).  ``<layer>.r_cond_estimate`` carries the
     ``condition_estimate`` value (batches: the member with the worst diag
-    ratio is estimated — the screen is free, the estimate is O(n^2)/iter);
-    ``<layer>.r_cond_proxy`` is kept as a legacy alias of the same value.
+    ratio is estimated — the screen is free, the estimate is O(n^2)/iter).
     Skips under tracing or the null registry."""
     reg = _active()
     if not reg.enabled or not _concrete(R):
@@ -109,7 +107,6 @@ def factor_health(R, layer: str, **labels) -> None:
         Rf = Rf[int(np.argmax(ratios))]
     cond = condition_estimate(Rf)
     reg.gauge(f"{layer}.r_cond_estimate", **labels).set(cond)
-    reg.gauge(f"{layer}.r_cond_proxy", **labels).set(cond)  # legacy alias
 
 
 def orthogonality_loss(A, R) -> float:

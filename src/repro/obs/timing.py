@@ -18,7 +18,7 @@ import time
 
 import jax
 
-__all__ = ["block_ready", "device_timer", "time_dispatch"]
+__all__ = ["block_ready", "device_timer"]
 
 
 def _has_tracer(tree) -> bool:
@@ -72,10 +72,3 @@ def device_timer():
     if t.seconds is None:
         t.stop()
 
-
-def time_dispatch(fn, *args, **kwargs):
-    """``(out, seconds)`` of one blocked dispatch of ``fn(*args, **kwargs)``."""
-    with device_timer() as t:
-        out = fn(*args, **kwargs)
-        t.stop(out)
-    return out, t.seconds

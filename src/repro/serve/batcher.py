@@ -26,10 +26,15 @@ oldest open batch (tickets resolve to ``ShedError``) — see
 ``repro.serve.policy``.  Close reasons, sheds, and rejects are all counted
 (``serve.batch_close{kind,reason}``, ``serve.requests_shed``,
 ``serve.admission_rejected``) next to the legacy serving metric families.
+
+Host spans (``repro.obs.span``, on whenever a profiler trace is active):
+``repro/serve/submit`` around each submit, ``repro/serve/close`` around
+each close, carrying its ``kind``, ``cycle`` and ``n``; the dispatcher's
+stage spans nest inside it.  The batcher also spans every garbage-collector
+pass (``repro/host/gc``).
 """
 from __future__ import annotations
 
-import contextlib
 import time
 from dataclasses import dataclass, field
 
@@ -100,6 +105,7 @@ class ContinuousBatcher:
         self._has_deadlines = any(
             t.deadline is not None
             for t in (*self.policy.tiers.values(), self.policy.default))
+        obs.install_gc_spans()
 
     # ------------------------------------------------------------- queries
     def _kind_depth(self, kind: str) -> int:
@@ -113,7 +119,8 @@ class ContinuousBatcher:
     # ----------------------------------------------------------- admission
     def submit(self, kind: str, *args, **kwargs) -> Ticket:
         """Build a typed request and admit it (the ``submit_*`` entry)."""
-        return self.admit(make_request(kind, *args, **kwargs))
+        with obs.span("repro/serve/submit"):
+            return self.admit(make_request(kind, *args, **kwargs))
 
     def admit(self, request: Request) -> Ticket:
         """Admit one request into its group's open batch.
@@ -208,36 +215,36 @@ class ContinuousBatcher:
         """Hand one open batch to the dispatcher and store its results."""
         key = batch.key
         kind = key[0]
-        del self._open[key]
-        rec = obs.enabled()
-        if rec:
-            now = time.perf_counter()
-            qwait = obs.histogram("serve.queue_wait_seconds", kind=kind)
-            for ts in batch.submit_times:
-                qwait.observe(now - ts)
-            obs.histogram("serve.batch_size",
-                          kind=kind).observe(len(batch.requests))
-            obs.counter("serve.batch_close", kind=kind, reason=reason).inc()
-            group_span = obs.span(f"repro/serve/flush/{kind}")
-        else:
-            now = 0.0
-            group_span = contextlib.nullcontext()
-        with group_span:
+        with obs.span("repro/serve/close", kind=kind, cycle=batch.cycle,
+                      n=len(batch.requests)):
+            del self._open[key]
+            rec = obs.enabled()
+            if rec:
+                now = time.perf_counter()
+                qwait = obs.histogram("serve.queue_wait_seconds", kind=kind)
+                for ts in batch.submit_times:
+                    qwait.observe(now - ts)
+                obs.histogram("serve.batch_size",
+                              kind=kind).observe(len(batch.requests))
+                obs.counter("serve.batch_close", kind=kind,
+                            reason=reason).inc()
+            else:
+                now = 0.0
             outs, handles = self.dispatcher.dispatch(key, batch.requests,
                                                      cycle=batch.cycle)
-        if rec:
-            # with double buffering off, per-chunk dispatches blocked above,
-            # so this measures the whole cycle: stacking + dispatch + scatter;
-            # with it on, it measures host-side close cost only
-            obs.histogram("serve.flush_duration_seconds",
-                          kind=kind).observe(time.perf_counter() - now)
-            obs.counter("serve.requests_served",
-                        kind=kind).inc(len(batch.requests))
-            obs.gauge("serve.queue_depth",
-                      kind=kind).set(self._kind_depth(kind))
-        self._store(key, batch.cycle, outs)
-        self._handles[(key, batch.cycle)] = handles
-        self._cycles[key] = batch.cycle + 1
+            if rec:
+                # with double buffering off, per-chunk dispatches blocked
+                # above, so this measures the whole cycle: stacking +
+                # dispatch + scatter; with it on, host-side close cost only
+                obs.histogram("serve.flush_duration_seconds",
+                              kind=kind).observe(time.perf_counter() - now)
+                obs.counter("serve.requests_served",
+                            kind=kind).inc(len(batch.requests))
+                obs.gauge("serve.queue_depth",
+                          kind=kind).set(self._kind_depth(kind))
+            self._store(key, batch.cycle, outs)
+            self._handles[(key, batch.cycle)] = handles
+            self._cycles[key] = batch.cycle + 1
 
     def _store(self, key: tuple, cycle: int, outs) -> None:
         if (outs is not _SHED and outs

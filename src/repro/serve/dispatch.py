@@ -25,6 +25,16 @@ blocking, ``drain`` blocks), so the host stacks the next batch while the
 device chews the previous one.  ``double_buffer=False`` reproduces the
 legacy closed-loop timing: each chunk is finalized (and, under an installed
 ``repro.obs`` collector, blocked and timed) before the next is stacked.
+With double buffering on, even a collector never makes a close wait on the
+device: finalizing (metrics, and the served factor's health gauges, which
+copy it to the host) happens once ``pump`` sees a chunk ready, or in
+``drain``.
+
+**Spans.**  Each executor spans its stages on the profiler's clock:
+``repro/serve/stack`` (stacking and padding the requests' operands) and
+``repro/serve/results`` (slicing the per-request results); the solvers
+span the front end and the kernel launch between them.  ``pump`` is
+``repro/serve/pump``.
 
 **Executable cache.**  Sharded lstsq dispatch functions are built through a
 bounded per-server LRU (``ExecutableCache``) instead of a module-level
@@ -326,21 +336,19 @@ class Dispatcher:
             x = _pad_to(jnp.stack([r.arrays[i] for r in chunk]), P)
             return x if compute_dt == store_dt else x.astype(compute_dt)
 
-        Rb, Ub = stack(0), stack(1)
-        n, p = Rb.shape[2], Ub.shape[1]
-        if has_rhs:
-            db, Yb = stack(2), stack(3)
-            Rn, dn = qr_append_rows_batched(Rb, Ub, db, Yb,
-                                            **self._kernel_opts(store_dt))
-            Rn = Rn[:nb].astype(store_dt)  # down-cast to storage on return
-            dn = dn[:nb].astype(store_dt)
-            outs = [(Rn[i], dn[i]) for i in range(nb)]
-            w = n + Yb.shape[2]
-        else:
-            Rn = qr_append_rows_batched(Rb, Ub, **self._kernel_opts(store_dt))
-            Rn = Rn[:nb].astype(store_dt)
-            outs = [Rn[i] for i in range(nb)]
-            w = n
+        with obs.span("repro/serve/stack"):
+            cols = [stack(i) for i in range(4 if has_rhs else 2)]
+        n, p = cols[0].shape[2], cols[1].shape[1]
+        w = n + (cols[3].shape[2] if has_rhs else 0)
+        out = qr_append_rows_batched(*cols, **self._kernel_opts(store_dt))
+        with obs.span("repro/serve/results"):
+            if has_rhs:
+                Rn = out[0][:nb].astype(store_dt)  # down-cast to storage
+                dn = out[1][:nb].astype(store_dt)
+                outs = [(Rn[i], dn[i]) for i in range(nb)]
+            else:
+                Rn = out[:nb].astype(store_dt)
+                outs = [Rn[i] for i in range(nb)]
         return outs, nb * obs.ggr_append_flops(n, p, w), Rn
 
     def _exec_lstsq(self, chunk):
@@ -350,10 +358,11 @@ class Dispatcher:
         store_dt = str(chunk[0].arrays[0].dtype)
         compute_dt, _ = self._chunk_precision(store_dt)
         P = self.padded_chunk(nb, "lstsq", store_dt)
-        Ab = _pad_to(jnp.stack([r.arrays[0] for r in chunk]), P)
-        bb = _pad_to(jnp.stack([r.arrays[1] for r in chunk]), P)
-        if compute_dt != store_dt:
-            Ab, bb = Ab.astype(compute_dt), bb.astype(compute_dt)
+        with obs.span("repro/serve/stack"):
+            Ab = _pad_to(jnp.stack([r.arrays[0] for r in chunk]), P)
+            bb = _pad_to(jnp.stack([r.arrays[1] for r in chunk]), P)
+            if compute_dt != store_dt:
+                Ab, bb = Ab.astype(compute_dt), bb.astype(compute_dt)
         m, n = Ab.shape[1], Ab.shape[2]
         k = bb.shape[2] if bb.ndim > 2 else 1
         if self.mesh is None:
@@ -363,9 +372,10 @@ class Dispatcher:
                 ("lstsq", self.mesh, self.mesh_axis),
                 lambda: _build_sharded_lstsq(self.mesh, self.mesh_axis))
             xs, rs = fn(Ab, bb)
-        xs = xs[:nb].astype(store_dt)  # down-cast to storage on return
-        rs = rs[:nb].astype(store_dt)
-        outs = [(xs[i], rs[i]) for i in range(nb)]
+        with obs.span("repro/serve/results"):
+            xs = xs[:nb].astype(store_dt)  # down-cast to storage on return
+            rs = rs[:nb].astype(store_dt)
+            outs = [(xs[i], rs[i]) for i in range(nb)]
         return outs, nb * obs.lstsq_flops(m, n, k), None
 
     def _exec_kalman(self, chunk):
@@ -393,16 +403,18 @@ class Dispatcher:
                 x = _pad_to(jnp.stack([r.arrays[i] for r in chunk]), P)
             return x if compute_dt == store_dt else x.astype(compute_dt)
 
-        cols = [fld(i) for i in range(nfields)]
+        with obs.span("repro/serve/stack"):
+            cols = [fld(i) for i in range(nfields)]
         # per-filter state must always carry the padded batch dim
         n, w, p = cols[0].shape[-1], cols[3].shape[-1], cols[4].shape[-2]
         Rn, dn = kf_step_batched(cols[0], cols[1], cols[2], cols[3],
                                  cols[4], cols[5],
                                  cols[6] if has_G else None,
                                  **self._kernel_opts(store_dt))
-        Rn = Rn[:nb].astype(store_dt)  # down-cast to storage on return
-        dn = dn[:nb].astype(store_dt)
-        outs = [(Rn[i], dn[i]) for i in range(nb)]
+        with obs.span("repro/serve/results"):
+            Rn = Rn[:nb].astype(store_dt)  # down-cast to storage on return
+            dn = dn[:nb].astype(store_dt)
+            outs = [(Rn[i], dn[i]) for i in range(nb)]
         # fused SRIF stack: (w + 2n + p, w + n + 1) with w + n pivots
         # -> n + p rows ride below the (triangular-by-construction) top
         flops = nb * obs.ggr_append_flops(w + n, n + p, w + n + 1)
@@ -417,10 +429,11 @@ class Dispatcher:
         store_dt = str(chunk[0].arrays[0].dtype)
         compute_dt, _ = self._chunk_precision(store_dt)
         P = self.padded_chunk(nb, "lstsq_pivoted", store_dt)
-        Ab = _pad_to(jnp.stack([r.arrays[0] for r in chunk]), P)
-        bb = _pad_to(jnp.stack([r.arrays[1] for r in chunk]), P)
-        if compute_dt != store_dt:
-            Ab, bb = Ab.astype(compute_dt), bb.astype(compute_dt)
+        with obs.span("repro/serve/stack"):
+            Ab = _pad_to(jnp.stack([r.arrays[0] for r in chunk]), P)
+            bb = _pad_to(jnp.stack([r.arrays[1] for r in chunk]), P)
+            if compute_dt != store_dt:
+                Ab, bb = Ab.astype(compute_dt), bb.astype(compute_dt)
         m, n = Ab.shape[1], Ab.shape[2]
         k = bb.shape[2] if bb.ndim > 2 else 1
         if self.mesh is None:
@@ -431,10 +444,11 @@ class Dispatcher:
                 lambda: _build_sharded_lstsq_pivoted(self.mesh,
                                                      self.mesh_axis))
             xs, rs, rk = fn(Ab, bb)
-        xs = xs[:nb].astype(store_dt)  # down-cast to storage on return
-        rs = rs[:nb].astype(store_dt)
-        rk = rk[:nb]
-        outs = [(xs[i], rs[i], rk[i]) for i in range(nb)]
+        with obs.span("repro/serve/results"):
+            xs = xs[:nb].astype(store_dt)  # down-cast to storage on return
+            rs = rs[:nb].astype(store_dt)
+            rk = rk[:nb]
+            outs = [(xs[i], rs[i], rk[i]) for i in range(nb)]
         # pivoting adds the per-step suffix-norm matrix + swap on top of the
         # plain augmented sweep: ~2x the unpivoted macro-op count
         return outs, nb * 2.0 * obs.lstsq_flops(m, n, k), None
@@ -487,7 +501,15 @@ class Dispatcher:
 
     # -------------------------------------------------------- finalization
     def finalize(self, infl: InFlight) -> None:
-        """Block (if accounting) and record one chunk's dispatch metrics."""
+        """Record one chunk's dispatch metrics, if accounting.
+
+        Waits for the chunk only with double buffering off; with it on,
+        ``pump`` finalizes chunks already ready and ``drain`` blocks first.
+        Blocking on a ready chunk waits for nothing, but raises a deferred
+        device error here, in the chunk's failure domain.
+        ``serve.dispatch_seconds`` is host time from the chunk's stacking
+        to its results being seen ready, not device time.
+        """
         if infl.finalized:
             return
         infl.finalized = True
@@ -495,7 +517,8 @@ class Dispatcher:
             if infl.done_at is None and infl.ready():
                 infl.done_at = time.perf_counter()
             return
-        infl.block()
+        if not self.double_buffer or infl.ready():
+            infl.block()
         if infl.done_at is None:
             infl.done_at = time.perf_counter()
         kind = infl.key[0]
@@ -506,7 +529,7 @@ class Dispatcher:
         obs.record_dispatch("serve", infl.flops, infl.done_at - infl.t0,
                             by_dtype=obs.flops_by_dtype(infl.flops,
                                                         compute_dt, accum_dt),
-                            kind=kind, precision=compute_dt)
+                            rate=False, kind=kind, precision=compute_dt)
         padded = self.padded_chunk(infl.nb, kind, store_dt)
         obs.gauge("serve.padding_waste", kind=kind).set(
             (padded - infl.nb) / padded if padded else 0.0)
@@ -519,22 +542,23 @@ class Dispatcher:
         finalization failures are aggregated into one ``DrainError`` after
         every ready chunk has been attempted (a bad chunk never blocks its
         neighbors' finalization)."""
-        done = [i for i in self._inflight if i.ready()]
-        failures = []
-        ok = 0
-        for infl in done:
-            if infl.done_at is None:
-                infl.done_at = time.perf_counter()
-            try:
-                self.finalize(infl)
-                ok += 1
-            except Exception as e:  # noqa: BLE001 — aggregated below
-                infl.finalized = True  # terminal: don't re-finalize later
-                failures.append((infl, e))
-        self._inflight = [i for i in self._inflight if not i.finalized]
-        if failures:
-            raise DrainError(failures)
-        return ok
+        with obs.span("repro/serve/pump"):
+            done = [i for i in self._inflight if i.ready()]
+            failures = []
+            ok = 0
+            for infl in done:
+                if infl.done_at is None:
+                    infl.done_at = time.perf_counter()
+                try:
+                    self.finalize(infl)
+                    ok += 1
+                except Exception as e:  # noqa: BLE001 — aggregated below
+                    infl.finalized = True  # terminal: don't re-finalize later
+                    failures.append((infl, e))
+            self._inflight = [i for i in self._inflight if not i.finalized]
+            if failures:
+                raise DrainError(failures)
+            return ok
 
     def drain(self) -> int:
         """Block on and finalize ALL in-flight chunks.

@@ -285,15 +285,17 @@ def kf_step_batched(R: jax.Array, d: jax.Array, F: jax.Array, Qi: jax.Array,
             return M
         return jnp.broadcast_to(M, (B,) + M.shape)
 
-    Fb, Qib, Hb = bcast(F), bcast(Qi), bcast(H)
-    Gb = bcast(G)
-    zb = jnp.broadcast_to(z, (B,) + z.shape) if z.ndim == 1 else z
-    if Gb is None:
-        stacked = jax.vmap(
-            lambda r, dd, f, qi, h, zz: _step_stacked(r, dd, f, qi, h, zz, None)
-        )(R, d, Fb, Qib, Hb, zb)
-    else:
-        stacked = jax.vmap(_step_stacked)(R, d, Fb, Qib, Hb, zb, Gb)
+    with obs.span("repro/kalman/front"):
+        Fb, Qib, Hb = bcast(F), bcast(Qi), bcast(H)
+        Gb = bcast(G)
+        zb = jnp.broadcast_to(z, (B,) + z.shape) if z.ndim == 1 else z
+        if Gb is None:
+            stacked = jax.vmap(
+                lambda r, dd, f, qi, h, zz: _step_stacked(r, dd, f, qi, h, zz,
+                                                          None)
+            )(R, d, Fb, Qib, Hb, zb)
+        else:
+            stacked = jax.vmap(_step_stacked)(R, d, Fb, Qib, Hb, zb, Gb)
 
     n_piv = w + n
     if mesh is None:
@@ -306,11 +308,12 @@ def kf_step_batched(R: jax.Array, d: jax.Array, F: jax.Array, Qi: jax.Array,
         padded = pad_batch(stacked, shards * block_b)
         fn = _sharded_update_fn(mesh, mesh_axis, n_piv, backend, interpret,
                                 block_b, precision)
-        out = fn(padded)[:B]
+        with obs.span("repro/kernels/ggr_update"):
+            out = fn(padded)
+        out = out[:B]
+    # no factor-health gauge here: it would copy R' to the host and wait for
+    # the kernel; the serving engine records it once the chunk is ready
     R_new = jnp.triu(out[:, w:w + n, w:w + n])
-    # batch-wide posterior condition gauge (worst member estimated; see
-    # obs.factor_health) — eager fleets only, a no-op under tracing
-    obs.factor_health(R_new, "kalman")
     return R_new, out[:, w:w + n, w + n]
 
 

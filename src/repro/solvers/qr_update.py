@@ -33,6 +33,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.ggr import _eps_for, ggr_triangularize
 
 __all__ = [
@@ -103,18 +104,20 @@ def _update_stacked(stacked: jax.Array, n: int, backend: str,
     ``precision`` must already be resolved (a ``kernels.Precision`` or None)
     so it stays hashable through ``_sharded_update_fn``'s lru_cache.  The
     reference backend casts to the compute dtype and relies on
-    ``ggr_triangularize``'s own float32-promoted accumulation.
+    ``ggr_triangularize``'s own float32-promoted accumulation.  Spanned as
+    ``repro/kernels/ggr_update``: the launch of the update kernel.
     """
-    if backend == "reference":
-        if precision is not None:
-            stacked = stacked.astype(precision.compute)
-        return jax.vmap(lambda X: ggr_triangularize(X, n))(stacked)
-    if backend != "pallas":
-        raise ValueError(f"unknown backend {backend!r}")
-    from repro.kernels import batched_update  # deferred: solvers -> kernels edge
+    with obs.span("repro/kernels/ggr_update"):
+        if backend == "reference":
+            if precision is not None:
+                stacked = stacked.astype(precision.compute)
+            return jax.vmap(lambda X: ggr_triangularize(X, n))(stacked)
+        if backend != "pallas":
+            raise ValueError(f"unknown backend {backend!r}")
+        from repro.kernels import batched_update  # deferred: layer edge
 
-    return batched_update(stacked, n_pivots=n, block_b=block_b,
-                          interpret=interpret, precision=precision)
+        return batched_update(stacked, n_pivots=n, block_b=block_b,
+                              interpret=interpret, precision=precision)
 
 
 @functools.lru_cache(maxsize=32)
@@ -186,7 +189,9 @@ def qr_append_rows_batched(R: jax.Array, U: jax.Array,
         padded = pad_batch(stacked, shards * block_b)
         fn = _sharded_update_fn(mesh, mesh_axis, n, backend, interpret,
                                 block_b, precision)
-        out = fn(padded)[:B]
+        with obs.span("repro/kernels/ggr_update"):
+            out = fn(padded)
+        out = out[:B]
     R_new = jnp.triu(out[:, :n, :n])
     if d is None:
         return R_new

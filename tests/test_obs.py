@@ -8,7 +8,7 @@ Three layers of coverage:
   required-families gate, Prometheus text exposition shape;
 * integration — a real ``QRServer`` workload flushed under a collector must
   emit the full serving metric contract (queue-wait, flush-duration,
-  padding-waste, achieved GFLOP/s, ...) on both backends, and on a sharded
+  padding-waste, dispatch time, ...) on both backends, and on a sharded
   host mesh when one is available.
 """
 import math
@@ -117,11 +117,10 @@ def test_health_recorders_are_tracer_safe():
         jax.jit(lambda r: (obs.factor_health(r, "traced"), r)[1])(R)
     assert reg.find("unit.r_diag_min").value == 1.0
     assert reg.find("unit.r_diag_max").value == 4.0
-    # the proxy gauge now carries the iterative condition estimate (which
-    # converges from below), aliased to the legacy name
-    assert reg.find("unit.r_cond_proxy").value == pytest.approx(4.0, rel=1e-5)
+    # the iterative condition estimate converges from below
     assert (reg.find("unit.r_cond_estimate").value
-            == reg.find("unit.r_cond_proxy").value)
+            == pytest.approx(4.0, rel=1e-5))
+    assert reg.find("unit.r_cond_proxy") is None  # the alias is gone
     assert reg.find("traced.r_diag_min") is None
 
 
@@ -267,11 +266,16 @@ def _assert_serving_contract(reg, served, num):
     assert fls and all(h.min > 0.0 for h in fls)
     bss = [m for m in reg.collect() if m.name == "serve.batch_size"]
     assert bss and all(1 <= h.min and h.max <= num for h in bss)
-    # per-dispatch accounting: padding-waste fraction and achieved GFLOP/s
+    # per-dispatch accounting: padding-waste fraction, the host time from
+    # close to seen-ready and the work done (no flop rate: that time is not
+    # device time)
     pads = [m for m in reg.collect() if m.name == "serve.padding_waste"]
     assert pads and all(0.0 <= g.min and g.max < 1.0 for g in pads)
-    gfs = [m for m in reg.collect() if m.name == "serve.achieved_gflops"]
-    assert gfs and all(h.min > 0.0 for h in gfs)
+    dts = [m for m in reg.collect() if m.name == "serve.dispatch_seconds"]
+    assert dts and all(h.min > 0.0 for h in dts)
+    assert sum(m.value for m in reg.collect()
+               if m.name == "serve.flops_total") > 0
+    assert not any(m.name == "serve.achieved_gflops" for m in reg.collect())
     # first dispatch of each (group, chunk) signature is a compile
     misses = sum(m.value for m in reg.collect()
                  if m.name == "serve.executable_cache_miss")
@@ -280,7 +284,7 @@ def _assert_serving_contract(reg, served, num):
     depths = [m for m in reg.collect() if m.name == "serve.queue_depth"]
     assert depths and all(g.value == 0.0 for g in depths)
     # factor-health gauges ride along for R-producing kinds
-    assert any(m.name == "serve.r_cond_proxy" for m in reg.collect())
+    assert any(m.name == "serve.r_cond_estimate" for m in reg.collect())
 
 
 @pytest.mark.parametrize("backend", ["reference", "pallas"])

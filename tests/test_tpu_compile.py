@@ -2,8 +2,10 @@
 
 Nothing runs: each case lowers and compiles for a v5e chip that is described,
 not attached, so a kernel Mosaic would refuse fails here instead of on the
-chip.  The topology is described inside a fixture (never at import time) and
-the persistent compilation cache is off around these compiles.
+chip.  Each case also finds its kernels' stable ``name=`` in the lowered
+text, which is what a profiler trace names them by.  The topology is
+described inside a fixture (never at import time) and the persistent
+compilation cache is off around these compiles.
 """
 import functools
 
@@ -56,7 +58,12 @@ def _served(fn):
     return traced
 
 
+UPDATE, GEQRT = "ggr_batched_update", "ggr_batched_geqrt"
+PANEL, APPLY = "ggr_panel_factor", "ggr_apply_factors"
+
+
 def _cases():
+    """``{case: (fn, operand shapes, kernel names it must lower to)}``."""
     from repro.kernels.ggr_apply import _apply_factors_call
     from repro.kernels.ggr_panel import _batched_geqrt_call, _panel_factor_call
     from repro.kernels.ggr_update import _batched_update_call
@@ -67,32 +74,33 @@ def _cases():
     return {
         "update_append": (functools.partial(_batched_update_call, n_pivots=N,
                                             block_b=8, interpret=False),
-                          [(64, N + ROWS, N + 1)]),
+                          [(64, N + ROWS, N + 1)], [UPDATE]),
         "update_kalman": (functools.partial(_batched_update_call,
                                             n_pivots=2 * N, block_b=8,
                                             interpret=False),
-                          [(64,) + kal]),
+                          [(64,) + kal], [UPDATE]),
         # n=512 appends overflow the 16 MiB scoped VMEM at block_b=8 unless
         # the kernel narrows its batch tile (ggr_panel._fit_block)
         "update_wide": (functools.partial(_batched_update_call, n_pivots=512,
                                           block_b=8, interpret=False),
-                        [(64, 512 + ROWS, 513)]),
+                        [(64, 512 + ROWS, 513)], [UPDATE]),
         "update_fleet": (functools.partial(_batched_update_call, n_pivots=12,
                                            block_b=8, interpret=False),
-                         [(64, 21, 13)]),
+                         [(64, 21, 13)], [UPDATE]),
         "batched_geqrt": (functools.partial(_batched_geqrt_call, n_pivots=64,
                                             block_b=8, interpret=False),
-                          [(8, 64, 128)]),
+                          [(8, 64, 128)], [GEQRT]),
         "panel": (functools.partial(_panel_factor_call, pivot0=0,
                                     interpret=False),
-                  [(m_ls, 64)]),
+                  [(m_ls, 64)], [PANEL]),
         "apply": (functools.partial(_apply_factors_call, pivot0=0,
                                     block_w=N + 1, interpret=False),
-                  [(m_ls, 64), (m_ls, 64), (m_ls, N + 1)]),
+                  [(m_ls, 64), (m_ls, 64), (m_ls, N + 1)], [APPLY]),
         "lstsq_served": (_served(_batched_lstsq),
-                         [(64, m_ls, N), (64, m_ls, 1)]),
+                         [(64, m_ls, N), (64, m_ls, 1)], [PANEL, APPLY]),
         "lstsq_pivoted_served": (_served(_batched_lstsq_pivoted),
-                                 [(8, m_ls, N), (8, m_ls, 1)]),
+                                 [(8, m_ls, N), (8, m_ls, 1)],
+                                 [PANEL, APPLY]),
     }
 
 
@@ -101,8 +109,12 @@ def _cases():
                                   "batched_geqrt", "panel", "apply",
                                   "lstsq_served", "lstsq_pivoted_served"])
 def test_kernel_compiles_for_v5e(one_chip, case):
-    fn, shapes = _cases()[case]
+    fn, shapes, names = _cases()[case]
     args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
             for s in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
+    lowered = jax.jit(fn).lower(*args)
+    text = lowered.as_text()
+    for name in names:
+        assert f'kernel_name = "{name}"' in text
+    compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()
