@@ -32,7 +32,8 @@ copy it to the host) happens once ``pump`` sees a chunk ready, or in
 
 **Spans.**  Each executor spans its stages on the profiler's clock:
 ``repro/serve/stack`` (stacking and padding the requests' operands) and
-``repro/serve/results`` (slicing the per-request results); the solvers
+``repro/serve/results`` (splitting the per-request results, with
+arguments ``n`` and ``padded``); the solvers
 span the front end and the kernel launch between them.  ``pump`` is
 ``repro/serve/pump``.
 
@@ -46,6 +47,7 @@ the underlying jit caches.
 """
 from __future__ import annotations
 
+import functools
 import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
@@ -188,6 +190,24 @@ def _pad_to(x: jax.Array, batch: int) -> jax.Array:
     return pad_batch(x, batch)
 
 
+@functools.partial(jax.jit, static_argnames="dtypes")
+def _split_rows(fields: tuple, dtypes: tuple) -> tuple[tuple, tuple]:
+    """``(batches, rows)`` of one chunk's batched outputs: each field cast
+    to its dtype in ``dtypes`` (``None`` keeps it), and the tuple of its
+    rows.
+
+    Compiled per padded shape and dtypes, never per real request count:
+    callers keep the first ``nb`` rows and drop the pad lanes.  One call a
+    chunk, where eager row slices would each be a dispatch of their own.
+    On a mesh the rows come back replicated over it, as eager slices of the
+    batch-sharded outputs do.
+    """
+    batches = tuple(x if dt is None else x.astype(dt)
+                    for x, dt in zip(fields, dtypes))
+    return batches, tuple(tuple(x[i] for i in range(x.shape[0]))
+                          for x in batches)
+
+
 @dataclass
 class InFlight:
     """One enqueued chunk awaiting finalization (blocking + accounting)."""
@@ -197,7 +217,7 @@ class InFlight:
     t0: float              # host perf_counter at stack start
     outs: list             # per-request results (arrays or tuples of arrays)
     flops: float           # analytic useful-work flops for the chunk
-    r_factor: object       # batched R for factor-health gauges (or None)
+    r_factor: object       # padded batched R for factor-health gauges, or None
     record: bool           # obs was collecting at dispatch time
     done_at: float | None = None
     finalized: bool = False
@@ -341,14 +361,9 @@ class Dispatcher:
         n, p = cols[0].shape[2], cols[1].shape[1]
         w = n + (cols[3].shape[2] if has_rhs else 0)
         out = qr_append_rows_batched(*cols, **self._kernel_opts(store_dt))
-        with obs.span("repro/serve/results"):
-            if has_rhs:
-                Rn = out[0][:nb].astype(store_dt)  # down-cast to storage
-                dn = out[1][:nb].astype(store_dt)
-                outs = [(Rn[i], dn[i]) for i in range(nb)]
-            else:
-                Rn = out[:nb].astype(store_dt)
-                outs = [Rn[i] for i in range(nb)]
+        fields = tuple(out) if has_rhs else (out,)
+        (Rn, *_), outs = self._results("append", nb, fields,
+                                       (store_dt,) * len(fields))
         return outs, nb * obs.ggr_append_flops(n, p, w), Rn
 
     def _exec_lstsq(self, chunk):
@@ -372,10 +387,7 @@ class Dispatcher:
                 ("lstsq", self.mesh, self.mesh_axis),
                 lambda: _build_sharded_lstsq(self.mesh, self.mesh_axis))
             xs, rs = fn(Ab, bb)
-        with obs.span("repro/serve/results"):
-            xs = xs[:nb].astype(store_dt)  # down-cast to storage on return
-            rs = rs[:nb].astype(store_dt)
-            outs = [(xs[i], rs[i]) for i in range(nb)]
+        _, outs = self._results("lstsq", nb, (xs, rs), (store_dt, store_dt))
         return outs, nb * obs.lstsq_flops(m, n, k), None
 
     def _exec_kalman(self, chunk):
@@ -411,10 +423,8 @@ class Dispatcher:
                                  cols[4], cols[5],
                                  cols[6] if has_G else None,
                                  **self._kernel_opts(store_dt))
-        with obs.span("repro/serve/results"):
-            Rn = Rn[:nb].astype(store_dt)  # down-cast to storage on return
-            dn = dn[:nb].astype(store_dt)
-            outs = [(Rn[i], dn[i]) for i in range(nb)]
+        (Rn, _), outs = self._results("kalman", nb, (Rn, dn),
+                                      (store_dt, store_dt))
         # fused SRIF stack: (w + 2n + p, w + n + 1) with w + n pivots
         # -> n + p rows ride below the (triangular-by-construction) top
         flops = nb * obs.ggr_append_flops(w + n, n + p, w + n + 1)
@@ -444,14 +454,31 @@ class Dispatcher:
                 lambda: _build_sharded_lstsq_pivoted(self.mesh,
                                                      self.mesh_axis))
             xs, rs, rk = fn(Ab, bb)
-        with obs.span("repro/serve/results"):
-            xs = xs[:nb].astype(store_dt)  # down-cast to storage on return
-            rs = rs[:nb].astype(store_dt)
-            rk = rk[:nb]
-            outs = [(xs[i], rs[i], rk[i]) for i in range(nb)]
+        _, outs = self._results("lstsq_pivoted", nb, (xs, rs, rk),
+                                (store_dt, store_dt, None))
         # pivoting adds the per-step suffix-norm matrix + swap on top of the
         # plain augmented sweep: ~2x the unpivoted macro-op count
         return outs, nb * 2.0 * obs.lstsq_flops(m, n, k), None
+
+    def _results(self, kind: str, nb: int, fields: tuple,
+                 dtypes: tuple) -> tuple[tuple, list]:
+        """Split one chunk's batched, padded ``fields`` into per-request
+        results, in one compiled call (``_split_rows``).
+
+        Returns ``(batches, outs)``: each field cast to its storage dtype
+        at its padded size, and the first ``nb`` requests' results (a
+        tuple of fields each, or the one field's array).
+        """
+        with obs.span("repro/serve/results", n=nb,
+                      padded=fields[0].shape[0]):
+            batches, rows = _split_rows(fields, dtypes)
+            if len(rows) == 1:
+                outs = list(rows[0][:nb])
+            else:
+                outs = list(zip(*(r[:nb] for r in rows)))
+        if obs.enabled():
+            obs.counter("serve.result_splits", kind=kind).inc()
+        return batches, outs
 
     _EXECUTORS = {"append": _exec_append, "lstsq": _exec_lstsq,
                   "kalman": _exec_kalman,
@@ -534,7 +561,8 @@ class Dispatcher:
         obs.gauge("serve.padding_waste", kind=kind).set(
             (padded - infl.nb) / padded if padded else 0.0)
         if infl.r_factor is not None:
-            obs.factor_health(infl.r_factor, "serve", kind=kind)
+            # the executors hand over the padded batch: gauge the real rows
+            obs.factor_health(infl.r_factor[:infl.nb], "serve", kind=kind)
 
     def pump(self) -> int:
         """Finalize every in-flight chunk whose buffers are ready

@@ -411,6 +411,10 @@ class ResilientDispatcher(Dispatcher):
         for j, i in enumerate(live):
             entries[i] = ent[j]
             provs[i] = prv[j]
+        if r_factor is not None and len(sub) < n:
+            # ``finalize`` gauges the chunk's first ``nb`` rows of the
+            # padded factor: keep only the live lanes'
+            r_factor = r_factor[:len(sub)]
         return entries, provs, flops, r_factor
 
     def _dispatch_resilient(self, key: tuple, sub: list, depth: int = 0):

@@ -272,6 +272,22 @@ class TestQuarantine:
             assert np.isfinite(np.asarray(eng.result(t_ok))).all()
         assert _counter_sum(reg, "serve.quarantined", stage="precheck") == 1
 
+    def test_precheck_leaves_factor_gauges_to_live_lanes(self):
+        """After a precheck quarantine the chunk's factor-health gauges
+        read the survivors' factors alone, never a pad lane's zero R."""
+        rng = np.random.default_rng(15)
+        R, U = _append_args(rng)
+        U_bad = U.copy()
+        U_bad[0, 0] = np.inf
+        with obs.collecting() as reg:
+            eng = ContinuousBatcher(_fast())
+            eng.submit("append", R, U_bad)
+            t_ok = eng.submit("append", R, U)
+            eng.flush()
+            R_ok = np.asarray(eng.result(t_ok))
+        assert reg.find("serve.r_diag_min", kind="append").value == \
+            np.abs(np.diag(R_ok)).min() > 0.0
+
     def test_postcheck_isolates_nan_lane(self):
         rng = np.random.default_rng(6)
         A = rng.standard_normal((12, 3)).astype(np.float32)

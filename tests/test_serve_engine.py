@@ -439,3 +439,67 @@ def test_make_workload_kalman_mix_and_shared_models():
     appends = [r for r in reqs if r[0] == "append"]
     assert any(len(r) == 3 for r in appends)
     assert any(len(r) == 5 for r in appends)
+
+
+# ---------------------------------------------------- per-request results
+def _kind_args(kind, rng, dt):
+    """One request of ``kind`` (``append_rhs``: append with a right-hand
+    side) with operands stored at ``dt``."""
+    a = lambda x: jnp.asarray(x, dt)  # noqa: E731
+    if kind in ("append", "append_rhs"):
+        R, U = _append_args(rng)
+        if kind == "append":
+            return "append", a(R), a(U)
+        return ("append", a(R), a(U), a(rng.standard_normal((6, 2))),
+                a(rng.standard_normal((3, 2))))
+    if kind == "kalman":
+        R, H = _append_args(rng)
+        F = np.eye(6) + 0.1 * rng.standard_normal((6, 6))
+        return ("kalman", a(R), a(rng.standard_normal(6)), a(F),
+                a(np.eye(6)), a(H), a(rng.standard_normal(3)))
+    A, b = _lstsq_args(rng)
+    if kind == "lstsq_pivoted":
+        A = A[:, :2] @ rng.standard_normal((2, 3)).astype(np.float32)
+    return kind, a(A), a(b)
+
+
+@pytest.mark.parametrize("nb", [13, 64])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kind", ["append", "append_rhs", "lstsq", "kalman",
+                                  "lstsq_pivoted"])
+def test_served_results_equal_eager_row_slices(kind, dtype, nb, monkeypatch):
+    """Every executor's per-request results are bitwise the eager
+    ``out[:nb].astype(store)[i]`` of its padded batched outputs, one
+    ``jax.Array`` per field at the storage dtype; ``lstsq_pivoted``'s rank
+    stays int32.  Under the f32 policy a bf16 group computes in f32 and
+    casts back on return."""
+    from repro.serve import dispatch
+
+    real = dispatch._split_rows
+    seen = []
+
+    def recording(fields, dtypes):
+        seen.append((fields, dtypes))
+        return real(fields, dtypes)
+
+    monkeypatch.setattr(dispatch, "_split_rows", recording)
+    rng = np.random.default_rng(70 + nb)
+    eng = ContinuousBatcher(Dispatcher(backend="reference", precision="f32"),
+                            retain_cycles=None)
+    tickets = [eng.submit(*_kind_args(kind, rng, dtype)) for _ in range(nb)]
+    eng.flush()
+    ((fields, dtypes),) = seen
+    padded = eng.dispatcher.padded_chunk(nb, tickets[0].kind, dtype)
+    assert all(f.shape[0] == padded for f in fields)
+    want_dt = ([dtype] * len(fields) if kind != "lstsq_pivoted"
+               else [dtype, dtype, "int32"])
+    for i, t in enumerate(tickets):
+        got = eng.result(t)
+        got = got if isinstance(got, tuple) else (got,)
+        assert len(got) == len(fields)
+        for g, f, dt, w in zip(got, fields, dtypes, want_dt):
+            expect = (f[:nb] if dt is None else f[:nb].astype(dt))[i]
+            assert isinstance(g, jax.Array)
+            assert g.dtype == expect.dtype == jnp.dtype(w)
+            assert g.shape == expect.shape
+            assert np.asarray(g).tobytes() == np.asarray(expect).tobytes()

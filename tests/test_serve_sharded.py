@@ -284,6 +284,62 @@ def test_sharded_continuous_batching_matches_single_device_subprocess():
     )
 
 
+def test_sharded_result_split_matches_eager_slices_subprocess():
+    """On a 4-way mesh the compiled results split hands every request the
+    bits and the sharding (replicated over the mesh) of the eager slices
+    ``out[:nb].astype(store)[i]`` of the batch-sharded outputs, and the
+    kernel kinds' results equal the single-device engine's bitwise."""
+    _run(
+        """
+        import numpy as np, jax
+        from repro.launch.serve_qr import make_workload
+        from repro.parallel.sharding import make_batch_mesh
+        from repro.serve import ContinuousBatcher, Dispatcher, dispatch
+        assert jax.device_count() == 4, jax.device_count()
+        mesh = make_batch_mesh(4)
+        reqs = make_workload(52, n=6, rows=3, k=1, seed=57)
+        real, seen = dispatch._split_rows, []
+
+        def recording(fields, dtypes):
+            seen.append((fields, dtypes))
+            return real(fields, dtypes)
+
+        def serve(mesh):
+            eng = ContinuousBatcher(
+                Dispatcher(backend="pallas", interpret=True, mesh=mesh),
+                retain_cycles=None)
+            ts = [eng.submit(r[0], *r[1:]) for r in reqs]
+            eng.flush()
+            res = [eng.result(t) for t in ts]
+            return ts, [r if isinstance(r, tuple) else (r,) for r in res]
+
+        dispatch._split_rows = recording
+        ts, sharded = serve(mesh)
+        dispatch._split_rows = real
+        _, single = serve(None)
+        by_group = {}
+        for t, res in zip(ts, sharded):
+            by_group.setdefault(t.group, []).append((t.index, res))
+        assert len(seen) == len(by_group) >= 5
+        for (fields, dtypes), lanes in zip(seen, by_group.values()):
+            nb = len(lanes)
+            assert nb < fields[0].shape[0]  # every group carries pad lanes
+            for i, res in lanes:
+                for g, f, dt in zip(res, fields, dtypes):
+                    e = (f[:nb] if dt is None else f[:nb].astype(dt))[i]
+                    assert g.sharding == e.sharding, (g.sharding, e.sharding)
+                    assert g.dtype == e.dtype
+                    assert np.asarray(g).tobytes() == np.asarray(e).tobytes()
+        for r, a, b in zip(reqs, sharded, single):
+            if r[0] in ("append", "kalman"):
+                for xa, xb in zip(a, b):
+                    np.testing.assert_array_equal(np.asarray(xa),
+                                                  np.asarray(xb))
+        print("SPLIT_SHARDED_OK")
+        """
+    )
+
+
 @pytest.mark.parametrize("env_dir", [None, "elsewhere"])
 def test_compile_cache_dir_follows_env(monkeypatch, tmp_path, env_dir):
     """``JAX_COMPILATION_CACHE_DIR`` wins and is left to JAX; without it the

@@ -98,10 +98,14 @@ def test_served_path_spans_nest_under_their_close(tmp_path):
     assert len(stages) == 2 * len(CLOSE_STAGES)
     for name, s, e, line, args in stages:
         # each stage lies inside one close, on its thread, and carries the
-        # close's arguments
+        # close's arguments; the results split adds its chunk's real and
+        # padded sizes
         (close,) = [c for c in closes
                     if c[3] == line and c[1] <= s and e <= c[2]]
-        assert args == close[4]
+        if name == "repro/serve/results":
+            assert args == {**close[4], "n": 8, "padded": 8}
+        else:
+            assert args == close[4]
     # submits and pumps are not part of a close
     for name, s, e, line, args in spans:
         if name in ("repro/serve/submit", "repro/serve/pump"):
@@ -159,6 +163,34 @@ def test_serving_under_spans_compiles_no_more(tmp_path, monkeypatch):
             fleet.step(engine)
         without = _backend_compiles(reg) - before
     assert with_spans <= without
+
+
+def test_result_split_compiles_once_per_padded_size(monkeypatch):
+    """Closes of 9 and then 13 requests both pad to 16: the second close's
+    results split compiles nothing, and a collector counts one split per
+    chunk."""
+    from repro.serve import dispatch
+
+    real = dispatch._split_rows
+    split_compiles = []
+
+    def counting(*a, **k):
+        before = _backend_compiles(reg)
+        out = real(*a, **k)
+        split_compiles.append(_backend_compiles(reg) - before)
+        return out
+
+    monkeypatch.setattr(dispatch, "_split_rows", counting)
+    engine = ContinuousBatcher(
+        Dispatcher(backend="reference", max_batch=16, double_buffer=True),
+        retain_cycles=None)
+    with obs.collecting() as reg:
+        _Fleet(9, seed=1).step(engine)
+        cached = real._cache_size()
+        _Fleet(13, seed=2).step(engine)
+        assert reg.find("serve.result_splits", kind="kalman").value == 2
+    assert real._cache_size() == cached
+    assert len(split_compiles) == 2 and split_compiles[1] == 0
 
 
 def test_collector_counts_compiles_while_installed():
