@@ -100,13 +100,15 @@ def _pivoted_sweep(X: jax.Array, n_pivots: int):
                              (X, jnp.arange(n_pivots, dtype=jnp.int32)))
 
 
-def ggr_qr_pivoted(A: jax.Array, rhs: jax.Array | None = None) -> PivotedQR:
+def ggr_qr_pivoted(A: jax.Array, rhs: jax.Array | None = None, *,
+                   route: str | None = None) -> PivotedQR:
     """Column-pivoted GGR QR of A with an optional rhs riding along.
 
     Tall A is first reduced unpivoted through the size-routed blocked driver
     (column norms are preserved by the orthogonal reduction, so pivoting on
     the small top block is exact QRCP); the pivoted sweep then runs on the
     ``(min(m, n), n [+ k])`` block.  ``rhs`` may be ``(m,)`` or ``(m, k)``.
+    ``route`` overrides the reduction's size rule (``solvers.lstsq_route``).
     """
     m, n = A.shape
     k = 0
@@ -117,7 +119,7 @@ def ggr_qr_pivoted(A: jax.Array, rhs: jax.Array | None = None) -> PivotedQR:
         X = jnp.concatenate([A, B.astype(A.dtype)], axis=1)
     tail = None
     if m > n:
-        X = _triangularize_auto(X, n)
+        X = _triangularize_auto(X, n, route)
         if rhs is not None:
             tail = jnp.sum(X[n:, n:].astype(
                 jnp.promote_types(X.dtype, jnp.float32)) ** 2, axis=0)
@@ -173,17 +175,19 @@ def _min_norm_from_state(R, d, perm, tail, rank):
 
 
 def lstsq_pivoted(A: jax.Array, b: jax.Array,
-                  rcond: float | None = None) -> PivotedLstsq:
+                  rcond: float | None = None, *,
+                  route: str | None = None) -> PivotedLstsq:
     """Rank-aware min ||Ax - b||: pivoted QR + min-norm solve.
 
     Unlike ``solvers.ggr_lstsq`` this never divides by a collapsed pivot:
     the numerical rank r comes from ``estimate_rank(R, rcond)`` and the
     solution is the minimum-norm x over the rank-r truncation — the same
     contract as ``numpy.linalg.lstsq`` (whose ``rcond`` this mirrors),
-    computed without an SVD.  Accepts m < n as well.
+    computed without an SVD.  Accepts m < n as well.  ``route`` is
+    ``ggr_qr_pivoted``'s.
     """
     vec = b.ndim == 1
-    st = ggr_qr_pivoted(A, b)
+    st = ggr_qr_pivoted(A, b, route=route)
     rank = estimate_rank(st.R, rcond)
     x, resid = _min_norm_from_state(st.R, st.d, st.perm, st.tail, rank)
     if vec:

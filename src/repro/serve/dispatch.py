@@ -33,11 +33,19 @@ copy it to the host) happens once ``pump`` sees a chunk ready, or in
 **Spans.**  Each executor spans its stages on the profiler's clock:
 ``repro/serve/stack`` (stacking and padding the requests' operands) and
 ``repro/serve/results`` (splitting the per-request results, with
-arguments ``n`` and ``padded``); the solvers
-span the front end and the kernel launch between them.  ``pump`` is
+arguments ``n`` and ``padded``); between them the solvers span the
+front end and the kernel launch, and the solve kinds span their solve's
+launch as ``repro/lstsq/solve`` (``m``, ``n``, ``route``).  ``pump`` is
 ``repro/serve/pump``.
 
-**Executable cache.**  Sharded lstsq dispatch functions are built through a
+**Solves.**  ``lstsq`` and ``lstsq_pivoted`` share one executor and one
+compiled solve (``_batched_solve``), keyed on what it runs: the route
+(blocked panel kernels or the plain sweep; ``backend="reference"`` always
+takes the sweep, which calls no kernel) and, for the blocked route, the
+schedule override and interpret mode in force (``_solve_key``).
+
+**Executable cache.**  Sharded solve dispatch functions (per kind and
+mesh) are built through a
 bounded per-server LRU (``ExecutableCache``) instead of a module-level
 ``functools.lru_cache(maxsize=None)`` — a long-lived server that cycles
 meshes no longer pins dead ``Mesh`` objects (and their device buffers)
@@ -127,57 +135,77 @@ class ExecutableCache:
         self._entries.clear()
 
 
-@jax.jit
-def _batched_lstsq(Ab, bb):
-    """jit'd once — repeated flushes of the same padded shape reuse the
-    executable."""
+def _solve_key(backend: str, m: int, n: int) -> tuple:
+    """``(route, schedule, interpret)``: what one batched (m, n) solve runs,
+    resolved on the host so that it keys the compiled solve.
+
+    ``backend="reference"`` takes the plain-``jnp`` sweep, which calls no
+    kernel; so does a problem below ``solvers.lstsq_route``'s blocked size.
+    A blocked solve carries the ``kernels.backend.degraded_mode`` schedule
+    override (None: the driver's own choice) and the interpret mode, both
+    otherwise read only while tracing: a rung of the resilience ladder
+    then gets a solve compiled for it, not the one cached before it.
+    """
+    from repro.kernels import default_interpret
+    from repro.kernels.backend import forced_schedule
+    from repro.solvers import lstsq_route
+
+    route = "sweep" if backend == "reference" else lstsq_route(m, n)
+    if route == "sweep":
+        return route, None, None
+    return route, forced_schedule(), default_interpret()
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("pivoted", "route", "schedule", "interpret"))
+def _batched_solve(Ab, bb, pivoted: bool, route: str,
+                   schedule: str | None, interpret: bool | None):
+    """The vmapped least-squares solve of a padded batch: ``(x, resid)``,
+    or with ``pivoted`` the rank-revealing ``(x, resid, rank)``.
+
+    Compiled per padded shape and ``_solve_key``; the key's schedule and
+    interpret mode are what the blocked driver's kernels are traced with.
+    The padded lanes are all-zero problems, exact fixed points of both
+    sweeps (the pivoted one gives rank 0, x = 0), so slicing them off is
+    lossless.
+    """
+    from repro.kernels.backend import degraded_mode
+    from repro.ranks import lstsq_pivoted
     from repro.solvers import ggr_lstsq
 
-    return jax.vmap(lambda A, b: ggr_lstsq(A, b)[:2])(Ab, bb)  # (x, resid)
-
-
-def _build_sharded_lstsq(mesh, mesh_axis: str):
-    """jit'd shard_map lstsq dispatch for one mesh (cached per server)."""
-    from jax.sharding import PartitionSpec as P
-
-    # check_vma off, as in ``solvers.qr_update``: the map runs each shard's
-    # problems on their own (no collectives), and neither pallas_call nor
-    # the solvers' loop carries are annotated with varying axes
-    return jax.jit(jax.shard_map(
-        _batched_lstsq, mesh=mesh,
-        in_specs=(P(mesh_axis), P(mesh_axis)),
-        out_specs=(P(mesh_axis), P(mesh_axis)),
-        check_vma=False,
-    ))
-
-
-@jax.jit
-def _batched_lstsq_pivoted(Ab, bb):
-    """Rank-revealing batch: (x, resid, rank) per problem.
-
-    The padded lanes are all-zero problems, whose pivoted sweep is an exact
-    fixed point (rank 0, x = 0), so slicing them off is lossless — same
-    contract as the unpivoted path."""
-    from repro.ranks import lstsq_pivoted
-
     def one(A, b):
-        fit = lstsq_pivoted(A, b)
-        return fit.x, fit.resid, fit.rank
+        if pivoted:
+            fit = lstsq_pivoted(A, b, route=route)
+            return fit.x, fit.resid, fit.rank
+        return tuple(ggr_lstsq(A, b, route=route)[:2])
 
-    return jax.vmap(one)(Ab, bb)
+    with degraded_mode(interpret=interpret, schedule=schedule):
+        return jax.vmap(one)(Ab, bb)
 
 
-def _build_sharded_lstsq_pivoted(mesh, mesh_axis: str):
-    """jit'd shard_map pivoted-lstsq dispatch for one mesh (cached per
-    server)."""
+def _build_sharded_solve(mesh, mesh_axis: str, pivoted: bool):
+    """jit'd shard_map solve dispatch for one mesh (cached per server),
+    compiled per padded shape and ``_solve_key`` as ``_batched_solve`` is."""
     from jax.sharding import PartitionSpec as P
 
-    return jax.jit(jax.shard_map(
-        _batched_lstsq_pivoted, mesh=mesh,
-        in_specs=(P(mesh_axis), P(mesh_axis)),
-        out_specs=(P(mesh_axis), P(mesh_axis), P(mesh_axis)),
-        check_vma=False,
-    ))
+    spec = P(mesh_axis)
+
+    @functools.partial(jax.jit,
+                       static_argnames=("route", "schedule", "interpret"))
+    def run(Ab, bb, route, schedule, interpret):
+        # check_vma off, as in ``solvers.qr_update``: the map runs each
+        # shard's problems on their own (no collectives), and neither
+        # pallas_call nor the solvers' loop carries are annotated with
+        # varying axes
+        return jax.shard_map(
+            functools.partial(_batched_solve, pivoted=pivoted, route=route,
+                              schedule=schedule, interpret=interpret),
+            mesh=mesh, in_specs=(spec, spec),
+            out_specs=(spec,) * (3 if pivoted else 2),
+            check_vma=False,
+        )(Ab, bb)
+
+    return run
 
 
 def _pad_to(x: jax.Array, batch: int) -> jax.Array:
@@ -367,12 +395,27 @@ class Dispatcher:
         return outs, nb * obs.ggr_append_flops(n, p, w), Rn
 
     def _exec_lstsq(self, chunk):
-        """Stack + pad one lstsq chunk, dispatch the vmapped augmented
-        sweep (shard_mapped over the mesh when one is set)."""
+        """One lstsq chunk: ``(x, resid)`` per request (``_exec_solve``)."""
+        return self._exec_solve("lstsq", chunk)
+
+    def _exec_solve(self, kind: str, chunk):
+        """Stack + pad one ``lstsq`` or ``lstsq_pivoted`` chunk and launch
+        the vmapped solve (``_batched_solve``, shard_mapped over the mesh
+        when one is set), keyed on ``_solve_key``.
+
+        ``lstsq_pivoted`` runs the QRCP min-norm solve
+        (``repro.ranks.lstsq_pivoted``); its per-request result is ``(x,
+        resid, rank)``, rank kept int32, never down-cast to the storage
+        dtype.  Under a collector each chunk counts its ``nb`` solves as
+        ``solvers.lstsq_solves{route}``.
+        """
+        from repro.solvers.lstsq import count_solves
+
+        pivoted = kind == "lstsq_pivoted"
         nb = len(chunk)
         store_dt = str(chunk[0].arrays[0].dtype)
         compute_dt, _ = self._chunk_precision(store_dt)
-        P = self.padded_chunk(nb, "lstsq", store_dt)
+        P = self.padded_chunk(nb, kind, store_dt)
         with obs.span("repro/serve/stack"):
             Ab = _pad_to(jnp.stack([r.arrays[0] for r in chunk]), P)
             bb = _pad_to(jnp.stack([r.arrays[1] for r in chunk]), P)
@@ -380,15 +423,23 @@ class Dispatcher:
                 Ab, bb = Ab.astype(compute_dt), bb.astype(compute_dt)
         m, n = Ab.shape[1], Ab.shape[2]
         k = bb.shape[2] if bb.ndim > 2 else 1
-        if self.mesh is None:
-            xs, rs = _batched_lstsq(Ab, bb)
-        else:
-            fn = self.executables.get(
-                ("lstsq", self.mesh, self.mesh_axis),
-                lambda: _build_sharded_lstsq(self.mesh, self.mesh_axis))
-            xs, rs = fn(Ab, bb)
-        _, outs = self._results("lstsq", nb, (xs, rs), (store_dt, store_dt))
-        return outs, nb * obs.lstsq_flops(m, n, k), None
+        key = _solve_key(self.backend, m, n)
+        with obs.span("repro/lstsq/solve", m=m, n=n, route=key[0]):
+            if self.mesh is None:
+                fields = _batched_solve(Ab, bb, pivoted, *key)
+            else:
+                fn = self.executables.get(
+                    (kind, self.mesh, self.mesh_axis),
+                    lambda: _build_sharded_solve(self.mesh, self.mesh_axis,
+                                                 pivoted))
+                fields = fn(Ab, bb, *key)
+        count_solves(key[0], nb)
+        dtypes = (store_dt, store_dt, None) if pivoted else (store_dt,) * 2
+        _, outs = self._results(kind, nb, fields, dtypes)
+        flops = nb * obs.lstsq_flops(m, n, k)
+        # pivoting adds the per-step suffix-norm matrix + swap on top of the
+        # plain augmented sweep: ~2x the unpivoted macro-op count
+        return outs, 2.0 * flops if pivoted else flops, None
 
     def _exec_kalman(self, chunk):
         """Stack + pad one kalman chunk, dispatch the fused SRIF step.
@@ -431,34 +482,9 @@ class Dispatcher:
         return outs, flops, Rn
 
     def _exec_lstsq_pivoted(self, chunk):
-        """Stack + pad one rank-revealing lstsq chunk: the vmapped QRCP
-        min-norm solve (``repro.ranks.lstsq_pivoted``), shard_mapped over
-        the mesh when one is set.  Per-request result is ``(x, resid,
-        rank)`` — rank stays int32, never down-cast to the storage dtype."""
-        nb = len(chunk)
-        store_dt = str(chunk[0].arrays[0].dtype)
-        compute_dt, _ = self._chunk_precision(store_dt)
-        P = self.padded_chunk(nb, "lstsq_pivoted", store_dt)
-        with obs.span("repro/serve/stack"):
-            Ab = _pad_to(jnp.stack([r.arrays[0] for r in chunk]), P)
-            bb = _pad_to(jnp.stack([r.arrays[1] for r in chunk]), P)
-            if compute_dt != store_dt:
-                Ab, bb = Ab.astype(compute_dt), bb.astype(compute_dt)
-        m, n = Ab.shape[1], Ab.shape[2]
-        k = bb.shape[2] if bb.ndim > 2 else 1
-        if self.mesh is None:
-            xs, rs, rk = _batched_lstsq_pivoted(Ab, bb)
-        else:
-            fn = self.executables.get(
-                ("lstsq_pivoted", self.mesh, self.mesh_axis),
-                lambda: _build_sharded_lstsq_pivoted(self.mesh,
-                                                     self.mesh_axis))
-            xs, rs, rk = fn(Ab, bb)
-        _, outs = self._results("lstsq_pivoted", nb, (xs, rs, rk),
-                                (store_dt, store_dt, None))
-        # pivoting adds the per-step suffix-norm matrix + swap on top of the
-        # plain augmented sweep: ~2x the unpivoted macro-op count
-        return outs, nb * 2.0 * obs.lstsq_flops(m, n, k), None
+        """One rank-revealing chunk: ``(x, resid, rank)`` per request
+        (``_exec_solve``)."""
+        return self._exec_solve("lstsq_pivoted", chunk)
 
     def _results(self, kind: str, nb: int, fields: tuple,
                  dtypes: tuple) -> tuple[tuple, list]:

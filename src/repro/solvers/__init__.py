@@ -53,7 +53,14 @@ from .kalman import (
     kf_step_batched,
     whiten_measurement,
 )
-from .lstsq import LstsqResult, RecursiveLS, RLSState, ggr_lstsq, solve_triangular
+from .lstsq import (
+    LstsqResult,
+    RecursiveLS,
+    RLSState,
+    ggr_lstsq,
+    lstsq_route,
+    solve_triangular,
+)
 from .qr_update import (
     qr_append_rows,
     qr_append_rows_batched,
@@ -78,6 +85,7 @@ __all__ = [
     "kf_smooth",
     "kf_step",
     "kf_step_batched",
+    "lstsq_route",
     "qr_append_rows",
     "qr_append_rows_batched",
     "qr_downdate_row",

@@ -22,8 +22,8 @@ from repro.core.ggr import ggr_triangularize
 
 from .qr_update import _tri_solve_lower, qr_append_rows, qr_downdate_row
 
-__all__ = ["LstsqResult", "RLSState", "RecursiveLS", "ggr_lstsq",
-           "solve_triangular", "state_integrity"]
+__all__ = ["LstsqResult", "RLSState", "RecursiveLS", "count_solves",
+           "ggr_lstsq", "lstsq_route", "solve_triangular", "state_integrity"]
 
 
 def state_integrity(state, max_cond: float | None = None) -> tuple[bool, str]:
@@ -61,13 +61,35 @@ _BLOCKED_MIN_ROWS = 256
 _BLOCKED_MIN_PIVOTS = 128
 
 
-def _triangularize_auto(X: jax.Array, n_pivots: int) -> jax.Array:
-    """Size-routed augmented triangularization (unblocked vs blocked panel)."""
-    m = X.shape[0]
+def lstsq_route(m: int, n_pivots: int) -> str:
+    """How an (m, ·) augmented sweep with ``n_pivots`` pivots is run:
+    ``"blocked"`` (the panel driver and its Pallas kernels) or ``"sweep"``
+    (the plain-``jnp`` column sweep, ``core.ggr.ggr_triangularize``)."""
     if m >= _BLOCKED_MIN_ROWS and n_pivots >= _BLOCKED_MIN_PIVOTS:
+        return "blocked"
+    return "sweep"
+
+
+def count_solves(route: str, n: int = 1) -> None:
+    """Count ``n`` solves that took ``route`` as ``solvers.lstsq_solves``
+    (a no-op without an installed collector).  Called on the host, never
+    under trace: by eager ``ggr_lstsq``, and per chunk by the serving
+    executors."""
+    if obs.enabled():
+        obs.counter("solvers.lstsq_solves", route=route).inc(n)
+
+
+def _triangularize_auto(X: jax.Array, n_pivots: int,
+                        route: str | None = None) -> jax.Array:
+    """Augmented triangularization on ``route`` (None: ``lstsq_route``)."""
+    if route is None:
+        route = lstsq_route(X.shape[0], n_pivots)
+    if route == "blocked":
         from repro.core.blocked import ggr_triangularize_blocked
 
         return ggr_triangularize_blocked(X, n_pivots)
+    if route != "sweep":
+        raise ValueError(f"unknown lstsq route {route!r}")
     return ggr_triangularize(X, n_pivots)
 
 
@@ -104,7 +126,8 @@ _RANK_COLLAPSE_EPS_MULT = 32.0
 
 
 def ggr_lstsq(A: jax.Array, b: jax.Array,
-              rcond: float | None = None) -> LstsqResult:
+              rcond: float | None = None, *,
+              route: str | None = None) -> LstsqResult:
     """min ||Ax - b|| for full-column-rank A (m >= n) via augmented GGR.
 
     One sweep triangularizes ``[A | b]`` to ``[R | d; 0 | r]``; x solves
@@ -119,6 +142,9 @@ def ggr_lstsq(A: jax.Array, b: jax.Array,
     raises a diagnostic ``ValueError`` on eager calls instead of silently
     dividing noise by it (the historical behaviour); traced/jitted calls
     cannot inspect values and keep the unchecked fast path.
+
+    ``route`` (``"blocked"`` | ``"sweep"``) overrides ``lstsq_route``'s
+    size rule for the augmented sweep, here and on the pivoted path.
     """
     m, n = A.shape
     if m < n:
@@ -126,14 +152,17 @@ def ggr_lstsq(A: jax.Array, b: jax.Array,
     if rcond is not None:
         from repro.ranks import lstsq_pivoted  # lazy: breaks the import cycle
 
-        fit = lstsq_pivoted(A, b, rcond=rcond)
+        fit = lstsq_pivoted(A, b, rcond=rcond, route=route)
         return LstsqResult(x=fit.x, resid=fit.resid, R=fit.R, d=fit.d)
     vec = b.ndim == 1
     B = b[:, None] if vec else b
-    X = _triangularize_auto(jnp.concatenate([A, B], axis=1), n)
+    if route is None:
+        route = lstsq_route(m, n)
+    X = _triangularize_auto(jnp.concatenate([A, B], axis=1), n, route)
     R = jnp.triu(X[:n, :n])
     d = X[:n, n:]
     if not isinstance(R, jax.core.Tracer):
+        count_solves(route)
         diag = jnp.abs(jnp.diagonal(R))
         dmin, dmax = float(jnp.min(diag)), float(jnp.max(diag))
         cliff = _RANK_COLLAPSE_EPS_MULT * float(jnp.finfo(R.dtype).eps)

@@ -58,6 +58,16 @@ def _served(fn):
     return traced
 
 
+def _solve(pivoted):
+    """The served solve of a padded batch, keyed as the executor keys it."""
+    def solve(Ab, bb):
+        from repro.serve.dispatch import _batched_solve, _solve_key
+
+        key = _solve_key("pallas", Ab.shape[1], Ab.shape[2])
+        return _batched_solve(Ab, bb, pivoted, *key)
+    return solve
+
+
 UPDATE, GEQRT = "ggr_batched_update", "ggr_batched_geqrt"
 PANEL, APPLY = "ggr_panel_factor", "ggr_apply_factors"
 
@@ -67,7 +77,6 @@ def _cases():
     from repro.kernels.ggr_apply import _apply_factors_call
     from repro.kernels.ggr_panel import _batched_geqrt_call, _panel_factor_call
     from repro.kernels.ggr_update import _batched_update_call
-    from repro.serve.dispatch import _batched_lstsq, _batched_lstsq_pivoted
 
     m_ls = 4 * N  # make_workload's lstsq rows
     kal = (N + 2 * N + ROWS, 2 * N + 1)  # SRIF stack, w = n
@@ -96,9 +105,9 @@ def _cases():
         "apply": (functools.partial(_apply_factors_call, pivot0=0,
                                     block_w=N + 1, interpret=False),
                   [(m_ls, 64), (m_ls, 64), (m_ls, N + 1)], [APPLY]),
-        "lstsq_served": (_served(_batched_lstsq),
+        "lstsq_served": (_served(_solve(False)),
                          [(64, m_ls, N), (64, m_ls, 1)], [PANEL, APPLY]),
-        "lstsq_pivoted_served": (_served(_batched_lstsq_pivoted),
+        "lstsq_pivoted_served": (_served(_solve(True)),
                                  [(8, m_ls, N), (8, m_ls, 1)],
                                  [PANEL, APPLY]),
     }
@@ -118,3 +127,24 @@ def test_kernel_compiles_for_v5e(one_chip, case):
         assert f'kernel_name = "{name}"' in text
     compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_tall_cell_kernel_pattern_finds_its_two_kernels_only(one_chip):
+    """The ``tall512.saturate`` cell's ``kernel_pattern`` occurs, in the v5e
+    program of its solve (64 problems of 512 x 128), in the instructions of
+    the panel and apply kernels and in no other: the profiler names each
+    device op by its instruction's text, which is what the benchmark
+    matches."""
+    import json
+    from pathlib import Path
+
+    cfg = json.loads((Path(__file__).resolve().parents[1] / "bench"
+                      / "configs" / "lstsq-tall512.json").read_text())
+    m, n = cfg["rows"], cfg["cols"]
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in [(64, m, n), (64, m)]]
+    text = jax.jit(_served(_solve(False))).lower(*args).compile().as_text()
+    hit = [line.split(" = ")[0].split()[-1].lstrip("%")
+           for line in text.splitlines()
+           if " = " in line and cfg["kernel_pattern"] in line]
+    assert sorted(h.split(".")[0] for h in hit) == [APPLY, PANEL]

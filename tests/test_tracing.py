@@ -115,6 +115,43 @@ def test_served_path_spans_nest_under_their_close(tmp_path):
     assert any(a.get("generation") == 2 for a in gen)
 
 
+def test_solve_span_nests_under_its_close(tmp_path):
+    """A solve kind's launch is ``repro/lstsq/solve``, between the close's
+    stack and results spans, carrying the close's arguments and its own
+    ``m``, ``n`` and ``route``."""
+    rng = np.random.default_rng(7)
+    A = rng.standard_normal((12, 3)).astype(np.float32)
+    b = rng.standard_normal(12).astype(np.float32)
+    engine = _engine()
+
+    def close(kind):
+        tickets = [engine.submit(kind, A, b) for _ in range(4)]
+        engine.flush()
+        engine.drain()
+        engine.poll()
+        return [engine.result(t) for t in tickets]
+
+    for kind in ("lstsq", "lstsq_pivoted"):
+        close(kind)  # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        for kind in ("lstsq", "lstsq_pivoted"):
+            close(kind)
+    spans = _host_spans(str(tmp_path))
+    closes = [s for s in spans if s[0] == "repro/serve/close"]
+    solves = [s for s in spans if s[0] == "repro/lstsq/solve"]
+    assert [c[4]["kind"] for c in closes] == ["lstsq", "lstsq_pivoted"]
+    assert len(solves) == 2
+    for name, s, e, line, args in solves:
+        (close_,) = [c for c in closes
+                     if c[3] == line and c[1] <= s and e <= c[2]]
+        assert args == {**close_[4], "m": 12, "n": 3, "route": "sweep"}
+        (stack,) = [x for x in spans if x[0] == "repro/serve/stack"
+                    and close_[1] <= x[1] and x[2] <= close_[2]]
+        (results,) = [x for x in spans if x[0] == "repro/serve/results"
+                      and close_[1] <= x[1] and x[2] <= close_[2]]
+        assert stack[2] <= s and e <= results[1]
+
+
 def test_spans_change_no_lowered_program(tmp_path):
     """A live host span enters no ``jax.named_scope``: JAX's name stack
     stays empty, and a jitted call under it hits the same executable."""
