@@ -6,11 +6,13 @@ kalman / lstsq_pivoted), the shard_map + ``pad_batch`` sharded path, the per-ser
 executable cache, and the double-buffering that overlaps host-side stacking
 of batch k+1 with batch k's device dispatch.
 
-**Padding before jit.**  Every chunk is zero-padded on the host to the
-granularity its kernel path actually runs at (``padded_chunk``: mesh →
+**Padding before jit.**  Every chunk is zero-padded to the granularity
+its kernel path actually runs at (``padded_chunk``: mesh →
 ``shards x block_b``, single-device pallas → ``block_b``) *before* the
-jitted entry point sees it.  Two chunk sizes that round to the same padded
-batch therefore hit ONE executable — which is also what makes the
+jitted entry point sees it: one compiled call (``_stack_rows``) stacks
+the requests' operands with all-zero fill rows up to that size.  Two chunk
+sizes that round to the same padded batch therefore hit ONE executable,
+the stack's and the kernel's — which is also what makes the
 ``serve.executable_cache_miss`` accounting honest: it keys on the padded
 size, not the raw chunk size (the old monolithic server keyed on the raw
 size and double-counted).  Zero problems are exact fixed points of the
@@ -32,8 +34,8 @@ copy it to the host) happens once ``pump`` sees a chunk ready, or in
 
 **Spans.**  Each executor spans its stages on the profiler's clock:
 ``repro/serve/stack`` (stacking and padding the requests' operands) and
-``repro/serve/results`` (splitting the per-request results, with
-arguments ``n`` and ``padded``); between them the solvers span the
+``repro/serve/results`` (splitting the per-request results), each with
+arguments ``n`` and ``padded``; between them the solvers span the
 front end and the kernel launch, and the solve kinds span their solve's
 launch as ``repro/lstsq/solve`` (``m``, ``n``, ``route``).  ``pump`` is
 ``repro/serve/pump``.
@@ -208,14 +210,30 @@ def _build_sharded_solve(mesh, mesh_axis: str, pivoted: bool):
     return run
 
 
-def _pad_to(x: jax.Array, batch: int) -> jax.Array:
-    """Zero-pad dim 0 up to exactly ``batch`` rows (no-op when already
-    there)."""
-    if x.shape[0] == batch:
-        return x
-    from repro.kernels import pad_batch
+@functools.lru_cache(maxsize=32)
+def _zero_row(shape: tuple, dtype, sharding) -> jax.Array:
+    """One all-zero operand row, the fill of a chunk's pad lanes: put on
+    ``sharding`` (committed) as a committed row is, or left on the default
+    device (uncommitted) with ``None``, so that a fill row never changes
+    the stacked batch's placement or ``_stack_rows``'s cache key."""
+    z = jnp.zeros(shape, dtype)
+    return z if sharding is None else jax.device_put(z, sharding)
 
-    return pad_batch(x, batch)
+
+@functools.partial(jax.jit, static_argnames="dtype")
+def _stack_rows(rows: tuple, dtype: str | None) -> tuple:
+    """One chunk's batches: each field's tuple of rows stacked and cast to
+    ``dtype`` (``None`` keeps each field's).
+
+    Callers fill each field up to the padded size with one all-zero row
+    (``_zero_row``), so this compiles per padded shape and dtype, never
+    per real request count.  One call a chunk, where an eager
+    ``jnp.stack`` would dispatch a copy per row and then a pad and a cast.
+    Nothing is donated: the rows stay their requests' (a retry or a
+    bisection stacks them again).
+    """
+    return tuple(jnp.stack(r) if dtype is None else jnp.stack(r).astype(dtype)
+                 for r in rows)
 
 
 @functools.partial(jax.jit, static_argnames="dtypes")
@@ -379,13 +397,9 @@ class Dispatcher:
         compute_dt, _ = self._chunk_precision(store_dt)
         P = self.padded_chunk(nb, "append", store_dt)
         has_rhs = chunk[0].arrays[2] is not None
-
-        def stack(i):
-            x = _pad_to(jnp.stack([r.arrays[i] for r in chunk]), P)
-            return x if compute_dt == store_dt else x.astype(compute_dt)
-
-        with obs.span("repro/serve/stack"):
-            cols = [stack(i) for i in range(4 if has_rhs else 2)]
+        with obs.span("repro/serve/stack", n=nb, padded=P):
+            cols = self._stack("append", chunk, range(4 if has_rhs else 2),
+                               P, store_dt, compute_dt)
         n, p = cols[0].shape[2], cols[1].shape[1]
         w = n + (cols[3].shape[2] if has_rhs else 0)
         out = qr_append_rows_batched(*cols, **self._kernel_opts(store_dt))
@@ -416,11 +430,9 @@ class Dispatcher:
         store_dt = str(chunk[0].arrays[0].dtype)
         compute_dt, _ = self._chunk_precision(store_dt)
         P = self.padded_chunk(nb, kind, store_dt)
-        with obs.span("repro/serve/stack"):
-            Ab = _pad_to(jnp.stack([r.arrays[0] for r in chunk]), P)
-            bb = _pad_to(jnp.stack([r.arrays[1] for r in chunk]), P)
-            if compute_dt != store_dt:
-                Ab, bb = Ab.astype(compute_dt), bb.astype(compute_dt)
+        with obs.span("repro/serve/stack", n=nb, padded=P):
+            Ab, bb = self._stack(kind, chunk, (0, 1), P, store_dt,
+                                 compute_dt)
         m, n = Ab.shape[1], Ab.shape[2]
         k = bb.shape[2] if bb.ndim > 2 else 1
         key = _solve_key(self.backend, m, n)
@@ -457,17 +469,20 @@ class Dispatcher:
         P = self.padded_chunk(nb, "kalman", store_dt)
         has_G = chunk[0].arrays[6] is not None
         nfields = 7 if has_G else 6
-
-        def fld(i):
-            if i >= 2 and all(r.arrays[i] is chunk[0].arrays[i]
-                              for r in chunk):
-                x = chunk[0].arrays[i]  # shared: broadcast, don't stack
-            else:
-                x = _pad_to(jnp.stack([r.arrays[i] for r in chunk]), P)
-            return x if compute_dt == store_dt else x.astype(compute_dt)
-
-        with obs.span("repro/serve/stack"):
-            cols = [fld(i) for i in range(nfields)]
+        with obs.span("repro/serve/stack", n=nb, padded=P):
+            shared = {i for i in range(2, nfields) if all(
+                r.arrays[i] is chunk[0].arrays[i] for r in chunk)}
+            stacked = iter(self._stack(
+                "kalman", chunk, [i for i in range(nfields) if i not in shared],
+                P, store_dt, compute_dt))
+            cols = []
+            for i in range(nfields):
+                if i not in shared:
+                    cols.append(next(stacked))
+                elif compute_dt == store_dt:
+                    cols.append(chunk[0].arrays[i])  # broadcast, don't stack
+                else:
+                    cols.append(chunk[0].arrays[i].astype(compute_dt))
         # per-filter state must always carry the padded batch dim
         n, w, p = cols[0].shape[-1], cols[3].shape[-1], cols[4].shape[-2]
         Rn, dn = kf_step_batched(cols[0], cols[1], cols[2], cols[3],
@@ -485,6 +500,39 @@ class Dispatcher:
         """One rank-revealing chunk: ``(x, resid, rank)`` per request
         (``_exec_solve``)."""
         return self._exec_solve("lstsq_pivoted", chunk)
+
+    def _stack(self, kind: str, chunk: list, idx, P: int, store_dt: str,
+               compute_dt: str) -> list:
+        """Operands ``idx`` of one chunk as batches padded to ``P`` and cast
+        to ``compute_dt``, in one compiled call (``_stack_rows``).
+
+        Each field's pad lanes are one cached all-zero row placed as the
+        field's first row is, so every chunk of one padded size runs one
+        executable and the batches are bitwise, and placed as, the eager
+        ``pad_batch(jnp.stack(rows), P).astype(compute_dt)``.  Fields placed
+        apart (on a mesh: a served state replicated over it beside a fresh
+        operand on one device) stack in one call per placement, since one
+        call would put every output on the union of its inputs' placements.
+        """
+        fill = P - len(chunk)
+        rows, places = [], {}
+        for j, i in enumerate(idx):
+            col = tuple(r.arrays[i] for r in chunk)
+            x = col[0]
+            where = x.sharding if x.committed else None
+            if fill:
+                col += (_zero_row(x.shape, x.dtype, where),) * fill
+            rows.append(col)
+            places.setdefault(where, []).append(j)
+        cast = None if compute_dt == store_dt else compute_dt
+        batches = [None] * len(rows)
+        for js in places.values():
+            out = _stack_rows(tuple(rows[j] for j in js), cast)
+            for j, x in zip(js, out):
+                batches[j] = x
+        if obs.enabled():
+            obs.counter("serve.batch_stacks", kind=kind).inc()
+        return batches
 
     def _results(self, kind: str, nb: int, fields: tuple,
                  dtypes: tuple) -> tuple[tuple, list]:

@@ -27,6 +27,7 @@ The zero-fault byte-compatibility bar (resilient results identical to the
 plain ``Dispatcher``) is asserted here AND enforced by ``bench_chaos
 --check`` in CI.
 """
+import contextlib
 import os
 import time
 
@@ -365,6 +366,61 @@ class TestQuarantine:
         for t, ref in ((t0, want[0]), (t2, want[2])):
             got = np.asarray(_as_tuple(eng.result(t))[0])
             assert np.array_equal(got, ref)
+
+    @pytest.mark.parametrize("stage", ["precheck", "bisect"])
+    def test_fill_rows_keep_survivor_bits(self, stage, monkeypatch):
+        """One poisoned request in a chunk of 9, caught by the pre-check (a
+        NaN operand) or isolated by bisection (an executor-raised poison):
+        every dispatch of the survivors stacks at the original padded width
+        16, not their own 8 or less, its missing lanes filled with zero
+        rows, and the survivors' results
+        are bitwise the fault-free run's.  Nothing is donated: no request
+        operand is deleted after dispatch."""
+        from repro.serve import dispatch
+
+        real, widths = dispatch._stack_rows, []
+
+        def recording(rows, dtype):
+            widths.append({len(col) for col in rows})
+            return real(rows, dtype)
+
+        rng = np.random.default_rng(16)
+        A = [jnp.asarray(rng.standard_normal((12, 3)), jnp.float32)
+             for _ in range(9)]
+        b = [jnp.asarray(rng.standard_normal((12, 1)), jnp.float32)
+             for _ in range(9)]
+        clean = ContinuousBatcher(_fast())
+        t_clean = [clean.submit("lstsq", *ab) for ab in zip(A, b)]
+        clean.flush()
+        want = [_as_tuple(clean.result(t)) for t in t_clean]
+
+        bad = 5
+        A_run = list(A)
+        if stage == "precheck":
+            A_run[bad] = A[bad].at[0, 0].set(jnp.nan)
+
+        class MarkedPoison:
+            def on_dispatch(self, kind, rung, dispatcher, chunk=None):
+                if any(r.arrays[0] is A_run[bad] for r in chunk or ()):
+                    raise InjectedPoison("marked request present")
+
+        monkeypatch.setattr(dispatch, "_stack_rows", recording)
+        with obs.collecting() as reg:
+            eng = ContinuousBatcher(_fast())
+            with (inject(MarkedPoison()) if stage == "bisect"
+                  else contextlib.nullcontext()):
+                tickets = [eng.submit("lstsq", *ab) for ab in zip(A_run, b)]
+                eng.flush()
+        assert _counter_sum(reg, "serve.quarantined", stage=stage) == 1
+        assert widths and all(w == {16} for w in widths)
+        for i, t in enumerate(tickets):
+            if i == bad:
+                with pytest.raises(PoisonedError):
+                    eng.result(t)
+                continue
+            for got, ref in zip(_as_tuple(eng.result(t)), want[i]):
+                assert np.asarray(got).tobytes() == np.asarray(ref).tobytes()
+        assert not any(x.is_deleted() for x in (*A, *A_run, *b))
 
 
 # ------------------------------------------------- satellite 1: drain/pump

@@ -503,3 +503,80 @@ def test_served_results_equal_eager_row_slices(kind, dtype, nb, monkeypatch):
             assert g.dtype == expect.dtype == jnp.dtype(w)
             assert g.shape == expect.shape
             assert np.asarray(g).tobytes() == np.asarray(expect).tobytes()
+
+
+# ---------------------------------------------------- stacked operands
+def _recording_stack(monkeypatch) -> list:
+    """Record every ``_stack_rows`` call as ``(rows, dtype, batches)``."""
+    from repro.serve import dispatch
+
+    real, seen = dispatch._stack_rows, []
+
+    def recording(rows, dtype):
+        out = real(rows, dtype)
+        seen.append((rows, dtype, out))
+        return out
+
+    monkeypatch.setattr(dispatch, "_stack_rows", recording)
+    return seen
+
+
+@pytest.mark.parametrize("nb", [13, 64])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kind", ["append", "append_rhs", "lstsq", "kalman",
+                                  "lstsq_pivoted"])
+def test_stacked_batches_equal_eager_stack(kind, dtype, nb, monkeypatch):
+    """Every executor stacks a chunk's operands in one compiled call whose
+    batches, as they go on to the solve or kernel, are bitwise the eager
+    ``pad_batch(jnp.stack(rows), P).astype(compute)``, with its dtype and
+    shape; the fill lanes are exactly zero.  Under the f32 policy a bf16
+    group's batches are f32."""
+    from repro.kernels import pad_batch
+
+    seen = _recording_stack(monkeypatch)
+    rng = np.random.default_rng(90 + nb)
+    eng = ContinuousBatcher(Dispatcher(backend="reference", precision="f32"),
+                            retain_cycles=None)
+    reqs = [_kind_args(kind, rng, dtype) for _ in range(nb)]
+    tickets = [eng.submit(*r) for r in reqs]
+    eng.flush()
+    ((rows, dtype_arg, batches),) = seen
+    P = eng.dispatcher.padded_chunk(nb, tickets[0].kind, dtype)
+    assert P > nb or nb == 64
+    # no operand is one shared array here, so every operand is stacked
+    assert len(rows) == len(batches) == len(reqs[0]) - 1
+    assert dtype_arg == (None if dtype == "float32" else "float32")
+    for f, (col, batch) in enumerate(zip(rows, batches)):
+        assert len(col) == P
+        assert all(col[i] is r[f + 1] for i, r in enumerate(reqs))
+        expect = pad_batch(jnp.stack(col[:nb]), P).astype(jnp.float32)
+        assert batch.dtype == expect.dtype == jnp.float32
+        assert batch.shape == expect.shape
+        assert np.asarray(batch).tobytes() == np.asarray(expect).tobytes()
+        assert not np.asarray(batch[nb:]).view(np.uint8).any()
+
+
+@pytest.mark.parametrize("kind", ["append", "lstsq"])
+def test_stack_compiles_once_per_padded_size(kind):
+    """With ``block_b`` 8, chunks of 9, 13 and 16 requests all stack at the
+    padded size 16 through one ``_stack_rows`` executable, and a chunk of
+    64 adds one more: the stack keys on the padded size, never on the real
+    request count."""
+    from repro.serve import dispatch
+
+    rng = np.random.default_rng(11)
+    # shapes no other test here stacks, so each new executable is this one's
+    args = (_append_args(rng, n=7, p=2) if kind == "append"
+            else _lstsq_args(rng, m=11, n=2))
+    eng = ContinuousBatcher(Dispatcher(backend="reference", block_b=8),
+                            retain_cycles=None)
+    start = dispatch._stack_rows._cache_size()
+    added = []
+    with obs.collecting() as reg:
+        for nb in (9, 13, 16, 64):
+            tickets = [eng.submit(kind, *args) for _ in range(nb)]
+            eng.flush()
+            assert all(eng.result(t) is not None for t in tickets)
+            added.append(dispatch._stack_rows._cache_size() - start)
+        assert reg.find("serve.batch_stacks", kind=kind).value == 4
+    assert added == [1, 1, 1, 2]

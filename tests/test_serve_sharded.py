@@ -340,6 +340,73 @@ def test_sharded_result_split_matches_eager_slices_subprocess():
     )
 
 
+def test_sharded_stack_matches_eager_stack_subprocess():
+    """On a 4-way mesh every compiled stack is bitwise, and placed as, the
+    eager ``pad_batch(jnp.stack(rows), P)``: on one device for fresh
+    operands, replicated over the mesh for a track's served state, which
+    the second step stacks beside fresh plots; the sharded ``lstsq`` and
+    ``kalman`` results equal the single-device engine's bitwise."""
+    _run(
+        """
+        import numpy as np, jax, jax.numpy as jnp
+        from repro.kernels import pad_batch
+        from repro.parallel.sharding import make_batch_mesh
+        from repro.serve import ContinuousBatcher, Dispatcher, dispatch
+        assert jax.device_count() == 4, jax.device_count()
+        mesh = make_batch_mesh(4)
+        NB = 13
+        rng = np.random.default_rng(58)
+        f32 = lambda x: jnp.asarray(x, jnp.float32)
+        F = f32(np.eye(6) + np.eye(6, k=3))
+        G = f32(np.vstack([0.5 * np.eye(3), np.eye(3)]))
+        Qi, H = f32(np.eye(3)), f32(np.hstack([np.eye(3), np.zeros((3, 3))]))
+        z = [[f32(rng.standard_normal(3)) for _ in range(NB)]
+             for _ in range(2)]
+        A = [f32(rng.standard_normal((12, 3))) for _ in range(NB)]
+        b = [f32(rng.standard_normal(12)) for _ in range(NB)]
+        real, seen = dispatch._stack_rows, []
+
+        def recording(rows, dtype):
+            out = real(rows, dtype)
+            seen.append((rows, out))
+            return out
+
+        def serve(mesh):
+            eng = ContinuousBatcher(
+                Dispatcher(backend="pallas", interpret=True, mesh=mesh),
+                retain_cycles=None)
+            state = [(f32(np.eye(6)), f32(np.zeros(6)))] * NB
+            for k in range(2):
+                ts = [eng.submit("kalman", R, d, F, Qi, H, zk, G)
+                      for (R, d), zk in zip(state, z[k])]
+                eng.flush()
+                state = [eng.result(t) for t in ts]
+            ts = [eng.submit("lstsq", *ab) for ab in zip(A, b)]
+            eng.flush()
+            return state + [eng.result(t) for t in ts]
+
+        dispatch._stack_rows = recording
+        sharded = serve(mesh)
+        dispatch._stack_rows = real
+        single = serve(None)
+        placed = set()
+        for rows, out in seen:
+            for col, got in zip(rows, out):
+                assert len(col) > NB  # every chunk fills pad lanes
+                e = pad_batch(jnp.stack(col[:NB]), len(col))
+                assert got.sharding == e.sharding, (got.sharding, e.sharding)
+                assert got.committed == e.committed
+                assert np.asarray(got).tobytes() == np.asarray(e).tobytes()
+                placed.add(type(got.sharding).__name__)
+        assert placed == {"SingleDeviceSharding", "NamedSharding"}, placed
+        for a, c in zip(sharded, single):
+            for xa, xc in zip(a, c):
+                assert np.asarray(xa).tobytes() == np.asarray(xc).tobytes()
+        print("STACK_SHARDED_OK")
+        """
+    )
+
+
 @pytest.mark.parametrize("env_dir", [None, "elsewhere"])
 def test_compile_cache_dir_follows_env(monkeypatch, tmp_path, env_dir):
     """``JAX_COMPILATION_CACHE_DIR`` wins and is left to JAX; without it the

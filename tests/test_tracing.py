@@ -98,11 +98,11 @@ def test_served_path_spans_nest_under_their_close(tmp_path):
     assert len(stages) == 2 * len(CLOSE_STAGES)
     for name, s, e, line, args in stages:
         # each stage lies inside one close, on its thread, and carries the
-        # close's arguments; the results split adds its chunk's real and
-        # padded sizes
+        # close's arguments; the stack and the results split add their
+        # chunk's real and padded sizes
         (close,) = [c for c in closes
                     if c[3] == line and c[1] <= s and e <= c[2]]
-        if name == "repro/serve/results":
+        if name in ("repro/serve/stack", "repro/serve/results"):
             assert args == {**close[4], "n": 8, "padded": 8}
         else:
             assert args == close[4]
@@ -204,8 +204,8 @@ def test_serving_under_spans_compiles_no_more(tmp_path, monkeypatch):
 
 def test_result_split_compiles_once_per_padded_size(monkeypatch):
     """Closes of 9 and then 13 requests both pad to 16: the second close's
-    results split compiles nothing, and a collector counts one split per
-    chunk."""
+    results split compiles nothing, and a collector counts one split and
+    one stack per chunk."""
     from repro.serve import dispatch
 
     real = dispatch._split_rows
@@ -226,6 +226,7 @@ def test_result_split_compiles_once_per_padded_size(monkeypatch):
         cached = real._cache_size()
         _Fleet(13, seed=2).step(engine)
         assert reg.find("serve.result_splits", kind="kalman").value == 2
+        assert reg.find("serve.batch_stacks", kind="kalman").value == 2
     assert real._cache_size() == cached
     assert len(split_compiles) == 2 and split_compiles[1] == 0
 
