@@ -39,7 +39,7 @@ is no ``m % tile == 0`` restriction.
 ``ggr_geqrt`` / ``ggr_tsqrt`` are the original explicit-Q tile primitives
 (still used by ``core.distributed`` and the Orthant optimizer), and
 ``ggr_qr_blocked_reference`` is the previous Python-unrolled driver with its
-serial TSQRT chain — kept as the baseline ``bench_blocked`` measures against.
+serial TSQRT chain — kept as an oracle the tests check against.
 """
 from __future__ import annotations
 
@@ -119,8 +119,8 @@ def ggr_qr_blocked_reference(A: jax.Array, tile: int = 128) -> jax.Array:
     """The previous blocked driver: Python-unrolled (p x q) tile loops with a
     serial per-row-tile TSQRT chain and one small GEMM per (i, j) tile.
 
-    Kept as the wall-clock baseline for ``bench_blocked`` and as a compact
-    executable statement of the PLASMA-style tile algorithm (§4.1.1).
+    Kept as a test oracle and as a compact executable statement of the
+    PLASMA-style tile algorithm (§4.1.1).
     """
     m, n = A.shape
     assert m % tile == 0 and n % tile == 0, "pad to tile multiples first"

@@ -41,16 +41,16 @@ max-error into the ``derived`` column (rows always have exactly 3 fields).
 
 Open-loop serving (continuous batching, per-kind deadlines, admission
 control, double-buffered dispatch) lives one layer down: compose
-``repro.serve.ContinuousBatcher`` directly — see ``docs/serving.md`` and
-``benchmarks/bench_serve_async.py`` for the Poisson load-generator
-evidence.  This module stays the stable closed-loop API.
+``repro.serve.ContinuousBatcher`` directly — see ``docs/serving.md``.
+This module stays the stable closed-loop API.
 
 Observability: the serving layers are instrumented with ``repro.obs`` —
 per-kind queue-depth gauges, submit->flush queue-wait and flush-duration
 histograms, batch-size, batch-close-reason, and padding-waste tracking,
-executable-cache-miss counters, and per-dispatch achieved-GFLOP/s derived
-from the ``core.counts`` analytic models.  All of it is a no-op until a
-collector is installed (``obs.install``/``obs.collecting``); ``--metrics
+executable-cache-miss counters, and per-dispatch host time and flop
+counts from the ``core.counts`` analytic models (no flop rate: that time
+is not device time).  All of it is a no-op until a collector is
+installed (``obs.install``/``obs.collecting``); ``--metrics
 PREFIX`` installs one for the CLI run and writes ``PREFIX.jsonl`` +
 ``PREFIX.prom`` snapshots (also triggered by the ``REPRO_OBS_SNAPSHOT``
 env var).  Catalog: ``docs/observability.md``.

@@ -57,21 +57,19 @@ REQUIRED_SERVE_FAMILIES = (
     "serve.requests_served",
 )
 
-# what an instrumented `bench_serve_async --check` run must additionally
-# emit: the continuous-batching close-reason counter plus both admission
-# outcomes (the bench runs a tiny admission drill so reject/shed families
-# are present even when the measured run never overloads).
+# what an instrumented continuous-batching run must additionally emit: the
+# close-reason counter plus both admission outcomes (a run that never
+# overloads needs an admission drill for the reject/shed families).
 REQUIRED_ASYNC_SERVE_FAMILIES = REQUIRED_SERVE_FAMILIES + (
     "serve.batch_close",
     "serve.admission_rejected",
     "serve.requests_shed",
 )
 
-# what an instrumented `bench_chaos --check` run must additionally emit:
-# the resilience layer's failure-domain, retry/degrade, quarantine, and
-# eager-purge counters plus the circuit-breaker state gauge.  The chaos
-# smoke fails CI when any of these families goes missing — a silent
-# resilience regression would otherwise look like a perfectly healthy run.
+# what an instrumented chaos run must additionally emit: the resilience
+# layer's failure-domain, retry/degrade, quarantine, and eager-purge
+# counters plus the circuit-breaker state gauge — a silent resilience
+# regression would otherwise look like a perfectly healthy run.
 REQUIRED_RESILIENCE_FAMILIES = (
     "serve.chunk_failures",
     "serve.retries",
@@ -208,12 +206,12 @@ def main(argv=None) -> None:
     """Snapshot validation CLI — the CI gate on serving metrics artifacts.
 
         python -m repro.obs.export --validate serve_metrics.jsonl \\
-            [--require fam1,fam2,...] [--preset serve|async]
+            [--require fam1,fam2,...] [--preset serve|async|chaos]
 
     Exits nonzero if the file is unreadable, schema-mismatched, or its LAST
     snapshot is missing any required family (default: the serving set;
-    ``--preset async`` gates on the continuous-batching superset that
-    ``bench_serve_async --check`` must emit).
+    ``--preset async`` adds the continuous-batching families, ``--preset
+    chaos`` the resilience ones).
     """
     ap = argparse.ArgumentParser()
     ap.add_argument("--validate", required=True, metavar="PATH",

@@ -4,10 +4,9 @@ The paper's performance claims are flops-per-watt claims, so the repo's own
 trajectory metric must be *achieved* flop rate, not speedup-over-self.  The
 flop numbers here come from the ``core.counts`` analytic multiplication
 models (eqs. 3-5 and their rectangular/append generalizations,
-``ggr_sweep_mults`` / ``ggr_append_mults``) — the same models
-``bench_counts`` validates against measured jaxpr counts — converted with
-``mults_to_flops`` (each macro-op multiplication pairs with one add in the
-DET2/FMA grids).
+``ggr_sweep_mults`` / ``ggr_append_mults``) — the same models the tests
+check against measured jaxpr counts — converted with ``mults_to_flops``
+(each macro-op multiplication pairs with one add in the DET2/FMA grids).
 
 ``record_dispatch`` is the single chokepoint every instrumented dispatch
 site funnels through: it observes ``<layer>.dispatch_seconds`` and bumps

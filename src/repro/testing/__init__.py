@@ -1,8 +1,8 @@
 """repro.testing: reusable numerical-verification harnesses.
 
-Not imported by any production path — tests and benchmarks pull from here
-so their matrix suites, error metrics, and tolerance budgets stay in one
-place instead of drifting apart file by file.
+Not imported by any production path — tests and ``chip_smoke.py`` pull
+from here so their matrix suites, error metrics, and tolerance budgets stay
+in one place instead of drifting apart file by file.
 """
 from .faults import (
     FaultInjector,

@@ -105,6 +105,24 @@ def test_blocked_f32_larger():
     np.testing.assert_allclose(np.abs(R[:192]), np.abs(Rnp), atol=5e-3)
 
 
+_F32_DRIVERS = {
+    "tree": lambda x: ggr_qr_blocked(x, schedule="tree"),
+    "fused": lambda x: ggr_qr_blocked(x, schedule="fused"),
+    "reference": lambda x: ggr_qr_blocked_reference(x, tile=128),
+    "unblocked": ggr_qr2,
+}
+
+
+@pytest.mark.parametrize("driver", sorted(_F32_DRIVERS))
+def test_f32_square_drivers_match_lapack(driver):
+    """Both blocked schedules, the tile reference driver and the unblocked
+    sweep on a 256 x 256 f32 matrix, against the float64 LAPACK R."""
+    A = _rand((256, 256), seed=5, dtype=np.float32)
+    R = np.asarray(_F32_DRIVERS[driver](jnp.asarray(A)))
+    Rnp = np.linalg.qr(A.astype(np.float64), mode="r")
+    np.testing.assert_allclose(np.abs(R[:256]), np.abs(Rnp), atol=5e-3)
+
+
 @pytest.mark.parametrize("schedule", SCHEDULES)
 def test_triangularize_rhs_rides(schedule):
     """Trailing rhs columns come back as Q^T-transformed data: the normal

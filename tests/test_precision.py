@@ -17,8 +17,7 @@ Coverage layers (ROADMAP item 8):
   loose tolerance but means the wide accumulation was lost;
 * serving: bf16 storage states round-trip through ``QRServer`` at their
   own dtype while a precision policy governs compute, and bf16 storage
-  doubles the dispatch block (the throughput lever ``bench_precision``
-  measures);
+  doubles the dispatch block;
 * filters: a bf16-state Kalman fleet stays innovation-consistent
   (mean NIS ~ p), single-device and under a 4-way host mesh.
 """

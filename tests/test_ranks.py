@@ -349,6 +349,27 @@ def test_sketch_lstsq_converges_where_plain_lsqr_cannot():
     assert np.linalg.norm(np.asarray(x_plain) - x0) > 0.1 * np.linalg.norm(x0)
 
 
+def test_sketch_lstsq_well_conditioned_reaches_oracle_residual():
+    """The same oracle at cond 1e2 and 2048 x 64: the sketched solve is not
+    only for hard problems, it reaches the optimal residual within 1e-6
+    relative in at most 50 iterations here too (f64)."""
+    from repro.testing import graded_matrix
+
+    m, n = 2048, 64
+    A = graded_matrix(m, n, 1e2, seed=11)
+    rng = np.random.default_rng(211)
+    x0 = rng.standard_normal(n)
+    Q, _ = np.linalg.qr(A)
+    r0 = rng.standard_normal(m)
+    r0 -= Q @ (Q.T @ r0)
+    r0 *= 0.1 / np.linalg.norm(r0)
+
+    fit = sketch_lstsq(jnp.asarray(A), jnp.asarray(A @ x0 + r0),
+                       iters=50, tol=1e-12)
+    assert int(fit.iters) <= 50
+    assert float(fit.resid) == pytest.approx(np.linalg.norm(r0), rel=1e-6)
+
+
 def test_sketch_lstsq_srht_and_sharded_paths_agree():
     from repro.testing import graded_matrix
 

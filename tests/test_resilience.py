@@ -24,8 +24,8 @@ Coverage for ``repro.serve.resilience`` plus the fault-injection harness in
   ``poison_workload`` never mutating its input.
 
 The zero-fault byte-compatibility bar (resilient results identical to the
-plain ``Dispatcher``) is asserted here AND enforced by ``bench_chaos
---check`` in CI.
+plain ``Dispatcher``) is asserted here; the seeded chaos run's bar is in
+``tests/test_serve_gates.py``.
 """
 import contextlib
 import os
