@@ -247,6 +247,28 @@ def kf_step(state: KalmanState, F: jax.Array, Qi: jax.Array, H: jax.Array,
                        step=state.step + 1)
 
 
+@jax.jit
+def _step_matrices(R, d, F, Qi, H, z, G):
+    """The B stacked step matrices of ``kf_step_batched``: shared (2-D)
+    model operands and a shared (1-D) ``z`` broadcast over the batch, then
+    ``_step_stacked`` vmapped over it.
+
+    Compiled per padded shape and model layout (which operands are shared,
+    whether ``G`` is None), so a chunk dispatches one call and traces
+    nothing.  Each lane runs ``_step_stacked`` as the eager vmap does, and
+    the matrices are bitwise the eager vmap's.
+    """
+    B = R.shape[0]
+
+    def bcast(M, ndim):
+        if M is None or M.ndim > ndim:
+            return M
+        return jnp.broadcast_to(M, (B,) + M.shape)
+
+    return jax.vmap(_step_stacked)(R, d, *(bcast(M, 2) for M in (F, Qi, H)),
+                                   bcast(z, 1), bcast(G, 2))
+
+
 def kf_step_batched(R: jax.Array, d: jax.Array, F: jax.Array, Qi: jax.Array,
                     H: jax.Array, z: jax.Array, G: jax.Array | None = None,
                     *, backend: str = "pallas", interpret: bool | None = None,
@@ -269,6 +291,10 @@ def kf_step_batched(R: jax.Array, d: jax.Array, F: jax.Array, Qi: jax.Array,
     ``qr_append_rows_batched``: sharded and single-device results agree
     bitwise.
 
+    The step matrices are built in one compiled call (``_step_matrices``),
+    compiled per padded shape and model layout: the operands' shapes and
+    dtypes, which model operands are shared, and whether ``G`` is given.
+
     ``precision``: mixed-precision policy (``Precision`` / name / None).
     The stacked step matrices run at the policy's compute dtype with wide
     in-kernel accumulation; the returned ``(R', d')`` carry compute dtype.
@@ -280,22 +306,8 @@ def kf_step_batched(R: jax.Array, d: jax.Array, F: jax.Array, Qi: jax.Array,
 
         precision = resolve_precision(precision)
 
-    def bcast(M):
-        if M is None or M.ndim == 3:
-            return M
-        return jnp.broadcast_to(M, (B,) + M.shape)
-
     with obs.span("repro/kalman/front"):
-        Fb, Qib, Hb = bcast(F), bcast(Qi), bcast(H)
-        Gb = bcast(G)
-        zb = jnp.broadcast_to(z, (B,) + z.shape) if z.ndim == 1 else z
-        if Gb is None:
-            stacked = jax.vmap(
-                lambda r, dd, f, qi, h, zz: _step_stacked(r, dd, f, qi, h, zz,
-                                                          None)
-            )(R, d, Fb, Qib, Hb, zb)
-        else:
-            stacked = jax.vmap(_step_stacked)(R, d, Fb, Qib, Hb, zb, Gb)
+        stacked = _step_matrices(R, d, F, Qi, H, z, G)
 
     n_piv = w + n
     if mesh is None:

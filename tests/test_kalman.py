@@ -351,3 +351,35 @@ def test_qr_server_sharded_kalman_flush_subprocess():
                          env=env, capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, f"stdout:\n{out.stdout}\nstderr:\n{out.stderr}"
     assert "KALMAN_SHARDED_OK" in out.stdout
+
+
+@pytest.mark.parametrize("B", [13, 64])
+@pytest.mark.parametrize("model", ["shared", "per_filter"])
+@pytest.mark.parametrize("with_G", [True, False])
+def test_step_matrices_bitwise_vs_eager_vmap(with_G, model, B):
+    """The compiled front's stacked step matrices are bitwise the eager
+    ``jax.vmap(_step_stacked)`` over the broadcast operands, with shared
+    (2-D) or per-filter (3-D) models, with and without ``G``."""
+    from repro.solvers.kalman import _step_matrices, _step_stacked
+
+    n, p = 6, 3
+    w = 3 if with_G else n  # no G: the noise enters as identity, w = n
+    rng = np.random.default_rng(64 + B)
+    f32 = lambda x: jnp.asarray(np.asarray(x, np.float32))  # noqa: E731
+    R = np.triu(rng.standard_normal((B, n, n)))
+    R[:, range(n), range(n)] = np.abs(R[:, range(n), range(n)]) + 1.0
+    R, d, z = f32(R), f32(rng.standard_normal((B, n))), f32(
+        rng.standard_normal((B, p)))
+    lead = (B,) if model == "per_filter" else ()
+    F = f32(np.eye(n) + 0.1 * rng.standard_normal(lead + (n, n)))
+    Qi = f32(np.triu(rng.standard_normal(lead + (w, w))) + 2.0 * np.eye(w))
+    H = f32(rng.standard_normal(lead + (p, n)))
+    G = f32(rng.standard_normal(lead + (n, w))) if with_G else None
+    bcast = lambda M: M if M is None or M.ndim == 3 else jnp.broadcast_to(  # noqa: E731
+        M, (B,) + M.shape)
+    expect = jax.vmap(_step_stacked)(R, d, bcast(F), bcast(Qi), bcast(H), z,
+                                     bcast(G))
+    got = _step_matrices(R, d, F, Qi, H, z, G)
+    assert got.dtype == expect.dtype == jnp.float32
+    assert got.shape == expect.shape == (B, w + 2 * n + p, w + n + 1)
+    assert np.asarray(got).tobytes() == np.asarray(expect).tobytes()

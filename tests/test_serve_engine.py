@@ -580,3 +580,47 @@ def test_stack_compiles_once_per_padded_size(kind):
             added.append(dispatch._stack_rows._cache_size() - start)
         assert reg.find("serve.batch_stacks", kind=kind).value == 4
     assert added == [1, 1, 1, 2]
+
+
+# ---------------------------------------------------- kalman front end
+_COMPILE_STAGES = ("jaxpr_trace", "jaxpr_to_mlir_module", "backend_compile")
+
+
+@pytest.mark.parametrize("model", ["shared", "per_filter_F"])
+def test_kalman_chunks_compile_nothing_after_the_first(model):
+    """Kalman chunks of one padded size, with a shared model or per-filter
+    ``F``, trace and compile nothing after the first chunk: the step
+    matrices are one compiled call, keyed on shapes and model layout, and
+    the stack, kernel and split reuse theirs."""
+    rng = np.random.default_rng(31)
+    f32 = lambda x: jnp.asarray(np.asarray(x, np.float32))  # noqa: E731
+    # shapes no other test serves, so the first chunk's executables are new
+    n, w, p, nb = 5, 2, 3, 13
+    F = np.eye(n) + np.eye(n, k=2)
+    G, Qi = f32(rng.standard_normal((n, w))), f32(np.eye(w))
+    H = f32(np.hstack([np.eye(p), np.zeros((p, n - p))]))
+    Fs = f32(F)
+    chunks = []
+    for _ in range(4):
+        reqs = []
+        for _ in range(nb):
+            R, _ = _append_args(rng, n=n, p=p)
+            Fi = (Fs if model == "shared"
+                  else f32(F + 0.01 * rng.standard_normal((n, n))))
+            reqs.append((f32(R), f32(rng.standard_normal(n)), Fi, Qi, H,
+                         f32(rng.standard_normal(p)), G))
+        chunks.append(reqs)
+    eng = ContinuousBatcher(Dispatcher(backend="pallas", block_b=8),
+                            retain_cycles=None)
+    counts = []
+    with obs.collecting() as reg:
+        for reqs in chunks:
+            tickets = [eng.submit("kalman", *r) for r in reqs]
+            eng.flush()
+            assert all(eng.result(t) is not None for t in tickets)
+            counts.append(tuple(
+                _counter_sum(reg, "jax.compile_events", stage=s)
+                for s in _COMPILE_STAGES))
+        assert reg.find("serve.batch_stacks", kind="kalman").value == 4
+    assert counts[0][0] > 0  # the first chunk traced what it runs
+    assert counts[1:] == counts[:1] * 3, counts
